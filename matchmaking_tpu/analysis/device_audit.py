@@ -354,6 +354,11 @@ _BOOL_PRIMS = {"and", "or", "not", "xor"}
 #: Index-consuming prims: taint flows from the OPERAND, never the indices
 #: (a clipped index read returns a real pool value).
 _GATHER_PRIMS = {"gather", "dynamic_slice", "take", "argmax", "argmin"}
+#: Higher-order primitives whose params carry a sub-jaxpr the taint walk
+#: steps into (exact names: ``jit`` — named ``pjit`` before JAX 0.7 —
+#: wraps every ``jnp.where``; ``remat2`` is ``jax.checkpoint``).
+_SUBJAXPR_PRIMS = {"jit", "scan", "while", "cond", "custom_jvp_call",
+                   "custom_vjp_call", "remat2", "closed_call"}
 
 
 def check_padded_lanes(fn, args, sentinel_arg: int, family: str,
@@ -408,9 +413,7 @@ def check_padded_lanes(fn, args, sentinel_arg: int, family: str,
                     out = {f for c in cases for f in c}
             elif name in _GATHER_PRIMS:
                 out = ins[0] if ins else set()
-            elif any(p in name for p in ("pjit", "scan", "while", "cond",
-                                         "custom_jvp", "custom_vjp",
-                                         "remat", "closed_call")):
+            elif name in _SUBJAXPR_PRIMS:
                 # Sub-jaxpr: map argument taints onto the inner invars,
                 # run, and map back.
                 subs = [s for v in eqn.params.values()

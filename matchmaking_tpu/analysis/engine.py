@@ -55,7 +55,7 @@ from matchmaking_tpu.analysis.core import (
 )
 
 #: Bump to invalidate every cache entry when rule semantics change.
-ANALYZER_VERSION = "2.4"
+ANALYZER_VERSION = "2.5"
 
 #: Per-file rule-module checkers (run per SourceFile; locks and protocol
 #: additionally take cross-file registries).

@@ -284,7 +284,7 @@ class ShardedRoleKernelSet:
                  frontier_k: int = 0, frontier_merge: str = "linear"):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from matchmaking_tpu.engine.sharded import AXIS, _shard_map
+        from matchmaking_tpu.engine.sharded import AXIS
 
         self.mesh = mesh
         self.n_shards = mesh.devices.size
@@ -329,9 +329,9 @@ class ShardedRoleKernelSet:
                       "enqueue_t", "active", "role_mask")}
         rep = P()
         self.search_step_packed = jax.jit(
-            _shard_map(self._step_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep),
-                       out_specs=(pool_spec, rep), check_vma=False),
+            jax.shard_map(self._step_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep),
+                          out_specs=(pool_spec, rep), check_vma=False),
             donate_argnums=0)
         if self.frontier_k:
             form_rows = (self.frontier_k
@@ -343,19 +343,19 @@ class ShardedRoleKernelSet:
                 widen_per_sec=widen_per_sec, max_threshold=max_threshold,
                 max_matches=self.max_matches, rounds=rounds)
             self.search_step_packed_ring = jax.jit(
-                _shard_map(self._step_shard_ring, mesh=mesh,
-                           in_specs=(pool_spec, rep),
-                           out_specs=(pool_spec, rep), check_vma=False),
+                jax.shard_map(self._step_shard_ring, mesh=mesh,
+                              in_specs=(pool_spec, rep),
+                              out_specs=(pool_spec, rep), check_vma=False),
                 donate_argnums=0)
         self.admit_packed = jax.jit(
-            _shard_map(self._admit_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._admit_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0)
         self.evict = jax.jit(
-            _shard_map(self._evict_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._evict_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0)
         self._sharding = NamedSharding(mesh, P(AXIS))
 

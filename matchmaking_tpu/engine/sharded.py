@@ -39,24 +39,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """shard_map across jax versions: new jax spells the replication-check
-    kwarg ``check_vma``, 0.4.x spells it ``check_rep`` (and hosts the
-    function under jax.experimental). One shim here serves every sharded
-    kernel family (1v1, team, role)."""
-    try:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=check_vma)
-    except TypeError:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
-
 from matchmaking_tpu.engine.kernels import (
     _NEG_INF,
     KernelSet,
@@ -236,7 +218,7 @@ class ShardedKernelSet:
                        "enqueue_t", "valid")}
 
         self.search_step = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 self._search_step_shard, mesh=mesh,
                 in_specs=(pool_spec, batch_spec, rep),
                 out_specs=(pool_spec, rep, rep, rep),
@@ -245,21 +227,21 @@ class ShardedKernelSet:
             donate_argnums=0,
         )
         self.admit = jax.jit(
-            _shard_map(self._admit_shard, mesh=mesh,
-                       in_specs=(pool_spec, batch_spec), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._admit_shard, mesh=mesh,
+                          in_specs=(pool_spec, batch_spec), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0,
         )
         self.evict = jax.jit(
-            _shard_map(self._evict_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._evict_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0,
         )
         # Packed I/O variants (one replicated f32[9,B] in / f32[3,B] out —
-        # single H2D/D2H RPC per window; see pool.PACKED_ROWS).
+        # single H2D/D2H transfer per window; see pool.PACKED_ROWS).
         self.search_step_packed = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 self._search_step_packed_shard, mesh=mesh,
                 in_specs=(pool_spec, rep), out_specs=(pool_spec, rep),
                 check_vma=False,
@@ -267,7 +249,7 @@ class ShardedKernelSet:
             donate_argnums=0,
         )
         self.admit_packed = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 lambda pool, packed: self._admit_shard(pool, unpack_batch(packed)),
                 mesh=mesh, in_specs=(pool_spec, rep), out_specs=pool_spec,
                 check_vma=False,
@@ -388,7 +370,7 @@ class ShardedKernelSet:
                           "enqueue_t", "active")}
             rep = P()
             fn = jax.jit(
-                _shard_map(
+                jax.shard_map(
                     functools.partial(self._search_step_bucket_shard, k=k),
                     mesh=self.mesh, in_specs=(pool_spec, rep),
                     out_specs=(pool_spec, rep), check_vma=False),

@@ -386,7 +386,7 @@ class ShardedTeamKernelSet:
         import numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from matchmaking_tpu.engine.sharded import AXIS, _shard_map
+        from matchmaking_tpu.engine.sharded import AXIS
 
         self.mesh = mesh
         self.n_shards = mesh.devices.size
@@ -437,9 +437,9 @@ class ShardedTeamKernelSet:
                       "enqueue_t", "active")}
         rep = P()
         self.search_step_packed = jax.jit(
-            _shard_map(self._step_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep),
-                       out_specs=(pool_spec, rep), check_vma=False),
+            jax.shard_map(self._step_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep),
+                          out_specs=(pool_spec, rep), check_vma=False),
             donate_argnums=0)
         if self.frontier_k:
             # Formation instance over the merged frontier buffer: D·K rows
@@ -456,19 +456,19 @@ class ShardedTeamKernelSet:
                 max_threshold=max_threshold, max_matches=self.max_matches,
                 rounds=rounds)
             self.search_step_packed_ring = jax.jit(
-                _shard_map(self._step_shard_ring, mesh=mesh,
-                           in_specs=(pool_spec, rep),
-                           out_specs=(pool_spec, rep), check_vma=False),
+                jax.shard_map(self._step_shard_ring, mesh=mesh,
+                              in_specs=(pool_spec, rep),
+                              out_specs=(pool_spec, rep), check_vma=False),
                 donate_argnums=0)
         self.admit_packed = jax.jit(
-            _shard_map(self._admit_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._admit_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0)
         self.evict = jax.jit(
-            _shard_map(self._evict_shard, mesh=mesh,
-                       in_specs=(pool_spec, rep), out_specs=pool_spec,
-                       check_vma=False),
+            jax.shard_map(self._evict_shard, mesh=mesh,
+                          in_specs=(pool_spec, rep), out_specs=pool_spec,
+                          check_vma=False),
             donate_argnums=0)
         self._sharding = NamedSharding(mesh, P(AXIS))
 

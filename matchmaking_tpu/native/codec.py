@@ -15,13 +15,15 @@ embedded NULs) come back as ``None`` and the caller re-encodes just those
 through the Python contract module.
 
 The library builds lazily with g++ (no deps; ~1 s once, cached next to the
-source). Everything degrades to pure Python when g++ or the build is
-unavailable — the native layer is an accelerator, never a requirement.
+source under a name that carries the source's hash). Everything degrades
+to pure Python when g++ or the build is unavailable — the native layer is
+an accelerator, never a requirement.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -33,7 +35,6 @@ log = logging.getLogger(__name__)
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "native", "codec.cc")
-_LIB = os.path.join(os.path.dirname(_SRC), "libmmcodec.so")
 
 # Status codes (keep in sync with codec.cc).
 OK = 0
@@ -56,15 +57,37 @@ _lib: ctypes.CDLL | None = None
 _build_failed = False
 
 
-def _build_locked() -> None:
-    """Compile libmmcodec.so from source when stale or missing (caller
-    holds ``_lock``). CI rebuilds through here (scripts/check.sh codec
+def _lib_path() -> str:
+    """The library built from the CURRENT codec.cc: its name carries a
+    hash of the source, so a binary built from other source (a copy of the
+    tree resets mtimes) is never the one loaded."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"libmmcodec-{digest}.so")
+
+
+def _build_locked() -> str:
+    """Compile the library for the current source unless it exists (caller
+    holds ``_lock``); returns its path and removes libraries built from
+    other source. CI rebuilds through here (scripts/check.sh codec
     section) so nothing ever depends on a checked-in binary."""
-    if (not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-        subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC],
-            check=True, capture_output=True, timeout=120)
+    lib = _lib_path()
+    if not os.path.exists(lib):
+        # Build beside, then rename: concurrent builders (test workers)
+        # never load a half-written file.
+        tmp = f"{lib}.{os.getpid()}.tmp"
+        subprocess.run(["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
+                       check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib)
+    native_dir = os.path.dirname(lib)
+    for name in os.listdir(native_dir):
+        if (name.startswith("libmmcodec") and name.endswith(".so")
+                and os.path.join(native_dir, name) != lib):
+            try:
+                os.unlink(os.path.join(native_dir, name))
+            except OSError:
+                pass
+    return lib
 
 
 def _load() -> ctypes.CDLL | None:
@@ -76,8 +99,7 @@ def _load() -> ctypes.CDLL | None:
         if _lib is not None or _build_failed:
             return _lib
         try:
-            _build_locked()
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(_build_locked())
             lib.mm_decode_requests.restype = ctypes.c_int64
             lib.mm_decode_requests.argtypes = [
                 ctypes.POINTER(ctypes.c_char_p),          # bufs
@@ -153,15 +175,16 @@ def available() -> bool:
 
 
 def rebuild(force: bool = False) -> bool:
-    """Rebuild libmmcodec.so from codec.cc (the CI seam: check.sh calls
+    """Rebuild the library from codec.cc (the CI seam: check.sh calls
     this so the parity fuzz gate never runs against a stale checked-in
     binary). ``force`` unlinks first. Returns availability afterwards."""
     global _lib, _build_failed
     with _lock:
-        if force and os.path.exists(_LIB):
+        lib = _lib_path()
+        if force and os.path.exists(lib):
             if _lib is not None:
                 return True  # already loaded in this process: can't unlink
-            os.unlink(_LIB)
+            os.unlink(lib)
         _build_failed = False
     return _load() is not None
 

@@ -1392,7 +1392,7 @@ class _QueueRuntime:
                 delivery.first_received = received_at
             if tr is not None:
                 # Same mark vocabulary as the per-delivery inline path so
-                # the trace taxonomy is stable across configs; the burst
+                # the trace classes are stable across configs; the burst
                 # handler's real cost is measured ONCE per burst into the
                 # `consume`/`decode` ingest categories instead of being
                 # smeared N× across member traces.
@@ -2766,7 +2766,7 @@ class _QueueRuntime:
         ``trace`` gets the "respond" mark at the moment the actual broker
         publish starts — splitting publish_lag (outcome-handling queueing
         on the loop, collect→respond) from the publish itself
-        (respond→publish) in the attribution taxonomy (PR 6 carry-over)."""
+        (respond→publish) in the attribution classes (PR 6 carry-over)."""
         if not reply_to:
             return
         r = self.replication
@@ -4308,6 +4308,12 @@ async def serve(stop: "asyncio.Event | None" = None,
             "joined DCN topology: process %d of %d", rank, nprocs)
 
     cfg = Config.from_env()
+    if cfg.engine.backend == "tpu":
+        # Before the first compile: a restart finds its executables again.
+        from matchmaking_tpu.utils.compile_cache import enable_compile_cache
+
+        logging.getLogger(__name__).info(
+            "compile cache at %s", enable_compile_cache())
     broker = None
     url = cfg.broker.url
     if url.startswith(("amqp://", "amqps://")):

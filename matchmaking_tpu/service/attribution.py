@@ -139,7 +139,7 @@ def classify(prev: str, cur: str) -> tuple[str, str]:
 def decompose_marks(
         marks) -> tuple[list[dict[str, Any]], float, float]:
     """THE gap walk: classify every adjacent pair of a mark sequence
-    (``[(name, t), ...]`` — tuples or JSON lists) into the taxonomy.
+    (``[(name, t), ...]`` — tuples or JSON lists) into the classes.
     Returns (gaps, work_s, wait_s); work + wait telescopes to the span.
     Shared by ``decompose`` (server side) and the trace_dump ``--gaps``
     waterfall (CLI side) so the two can never disagree."""

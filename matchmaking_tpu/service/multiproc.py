@@ -1,9 +1,8 @@
 """Multi-process ingress: an OTP-style supervisor over per-queue worker
 processes.
 
-The single-process service tops out around ~26k msg/s of broker ingress on
-one core (BENCH_SWEEP.md): decode + middleware + batcher all share the
-asyncio loop. The reference's scaling story is "add more consumers" — more
+A single service process is bound by one core: decode + middleware +
+batcher all share the asyncio loop. The reference's scaling story is "add more consumers" — more
 OS processes competing on the same AMQP broker. This module is that story
 for the rebuild (SURVEY.md §2 "AMQP consumer", §5 "Failure detection"):
 
@@ -131,13 +130,21 @@ class WorkerSupervisor:
         # (exception before run()'s finally) so the snapshot never leaks.
         atexit.register(self._cleanup_snapshot)
 
+        # The supervisor itself never imports JAX (this module needs only
+        # the config tree), so it holds no device: the chip stays free for
+        # the one worker that owns it.
         self.workers: list[_Worker] = []
         for i, qnames in enumerate(parts):
             env = dict(os.environ)
             env["MM_CONFIG_JSON"] = self._cfg_path
             env["MM_QUEUE_NAMES"] = ",".join(qnames)
             if i != device_worker and cfg.engine.backend != "cpu":
+                # One process per chip: only device_worker may bring up
+                # the accelerator backend. A CPU-engine worker that still
+                # initialised JAX's default (TPU) backend would fail, or
+                # wait on the chip its sibling holds.
                 env["MM_ENGINE_BACKEND"] = "cpu"
+                env["JAX_PLATFORMS"] = "cpu"
             if cfg.metrics_port:
                 env["MM_METRICS_PORT"] = str(cfg.metrics_port + i)
             env.update((extra_env or {}).get(i, {}))

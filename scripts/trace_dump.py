@@ -19,7 +19,7 @@ request slow" workflow:
     python scripts/trace_dump.py --incident live
     python scripts/trace_dump.py --incident incident_inc-000001_failover.json
 
-    # wait-vs-work gap waterfall (the ISSUE 6 attribution taxonomy)
+    # wait-vs-work gap waterfall (the ISSUE 6 attribution classes)
     python scripts/trace_dump.py --queue matchmaking.search --slow --gaps
 
     # per-queue attribution summary (/debug/attribution)
@@ -28,7 +28,7 @@ request slow" workflow:
     # match-quality & fairness summary (/debug/quality, ISSUE 8) — live,
     # or offline from a BENCH json's e2e_frontier rows
     python scripts/trace_dump.py --quality
-    python scripts/trace_dump.py --quality --bench-json BENCH_r06.json
+    python scripts/trace_dump.py --quality --bench-json <bench.json>
 
 Stdlib (urllib) transport — usable inside the service container; the
 ``--gaps`` classifier imports matchmaking_tpu.service.attribution, which is
@@ -235,7 +235,7 @@ def main(argv=None) -> None:
                          "renders that bundle offline via postmortem.py")
     ap.add_argument("--gaps", action="store_true",
                     help="render traces as a wait-vs-work gap waterfall "
-                         "(attribution taxonomy) instead of raw stages")
+                         "(attribution classes) instead of raw stages")
     ap.add_argument("--attribution", action="store_true",
                     help="per-queue attribution summary "
                          "(/debug/attribution)")

@@ -68,9 +68,9 @@ async def _http_json(url: str):
 # classification + decomposition units
 
 
-def test_classify_total_and_taxonomy():
+def test_classify_total_and_classes():
     """Every pair classifies somewhere (the telescoping identity needs a
-    total function), and the taxonomy pins the load-bearing gaps."""
+    total function), and the classes pins the load-bearing gaps."""
     assert classify("enqueue", "consume") == ("broker_dwell", WAIT)
     assert classify("chaos_drop", "consume") == ("redelivery_wait", WAIT)
     assert classify("batch", "flush") == ("batcher_hold", WAIT)

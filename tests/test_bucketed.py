@@ -570,7 +570,7 @@ def test_engine_frontier_fallback_above_ladder():
 def test_formation_report_and_marks():
     """Flat engines report no formation state; bucketed engines report it
     and stamp formation_bucketed device marks for the attribution
-    taxonomy."""
+    classes."""
     plain = _engine()
     assert plain.formation_report() is None
     e = _engine(bucketed=True, prune_window_blocks=8)

@@ -1111,7 +1111,6 @@ def test_device_ring_audit_rejects_split_permutation():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from matchmaking_tpu.analysis import device_audit
-    from matchmaking_tpu.engine.sharded import _shard_map
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("pool",))
 
@@ -1119,8 +1118,8 @@ def test_device_ring_audit_rejects_split_permutation():
         perm = [(0, 1), (1, 0), (2, 3), (3, 2)]  # two 2-cycles, no ring
         return lax.ppermute(x, "pool", perm)
 
-    f = _shard_map(bad_ring, mesh=mesh, in_specs=P("pool"),
-                   out_specs=P("pool"))
+    f = jax.shard_map(bad_ring, mesh=mesh, in_specs=P("pool"),
+                      out_specs=P("pool"), check_vma=False)
     closed = jax.make_jaxpr(f)(jnp.zeros(8))
     out = []
     device_audit._check_ring(closed, 4, "fixture.ring", "ctx", out)
@@ -1130,8 +1129,8 @@ def test_device_ring_audit_rejects_split_permutation():
         perm = [(i, (i + 1) % 4) for i in range(4)]
         return lax.ppermute(x, "pool", perm)
 
-    g = _shard_map(good_ring, mesh=mesh, in_specs=P("pool"),
-                   out_specs=P("pool"))
+    g = jax.shard_map(good_ring, mesh=mesh, in_specs=P("pool"),
+                      out_specs=P("pool"), check_vma=False)
     clean = []
     device_audit._check_ring(jax.make_jaxpr(g)(jnp.zeros(8)), 4,
                              "fixture.ring", "ctx", clean)

@@ -270,3 +270,29 @@ class TestNativeEncoder:
             ["unié"], ["b"], ["m1"], np.array([1.0]), np.array([1.0]),
             np.array([0.5]), np.array([0.0]), np.array([0.0]))
         assert bodies[0] is None and bodies[1] is None
+
+
+def test_library_is_keyed_on_source_hash(tmp_path, monkeypatch):
+    """A library built from OTHER source is never loaded, whatever its
+    mtime: a copied tree resets mtimes, so staleness is the source hash
+    carried in the library's name, not a timestamp comparison."""
+    import os
+    import shutil
+
+    src = tmp_path / "codec.cc"
+    shutil.copy(codec._SRC, src)
+    monkeypatch.setattr(codec, "_SRC", str(src))
+    first = codec._build_locked()
+    assert os.path.exists(first)
+    # Edit the source, then make the old binary look NEWER than it.
+    src.write_text(src.read_text() + "\n// edited\n")
+    later = os.path.getmtime(src) + 3600
+    os.utime(first, (later, later))
+    # The pre-hash name, fresh mtime and all, must not be picked up either.
+    legacy = tmp_path / "libmmcodec.so"
+    shutil.copy(first, legacy)
+    os.utime(legacy, (later, later))
+    second = codec._build_locked()
+    assert second != first
+    assert os.path.exists(second)
+    assert not os.path.exists(first) and not legacy.exists()

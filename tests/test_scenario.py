@@ -13,7 +13,7 @@
 - The 2-cell seeded mini-matrix smoke (scripts/check.sh runs this suite
   by marker): the REAL ``bench.py --scenario-matrix`` path in-process —
   artifact schema, autotuner audit ring non-empty on the overloaded cell,
-  per-cell abort isolation, and replay identity of the scenario digests
+  a failed cell failing the run, and replay identity of the scenario digests
   across two matrix runs.
 """
 
@@ -292,7 +292,7 @@ def _matrix_args(**over):
 _CELL_SCHEMA_KEYS = {
     "scenario", "seed", "duration_s", "scenario_digest", "offered",
     "matched", "shed", "expired", "slo_attainment", "admitted_p99_ms",
-    "attribution", "telemetry", "autotune", "cohorts", "abort_reason",
+    "attribution", "telemetry", "autotune", "cohorts",
 }
 
 
@@ -315,7 +315,6 @@ def test_mini_matrix_smoke_schema_audit_and_replay_identity(tmp_path):
         cells = out["scenario_matrix"]
         assert [c["scenario"] for c in cells] == ["steady", "flash-crowd"]
         for cell in cells:
-            assert cell["abort_reason"] is None, cell
             assert _CELL_SCHEMA_KEYS <= set(cell), sorted(cell)
             assert cell["telemetry"], "trajectory tail missing"
             assert cell["offered"] > 50 and cell["matched"] > 0
@@ -342,24 +341,18 @@ def test_mini_matrix_smoke_schema_audit_and_replay_identity(tmp_path):
         assert c1["offered"] == c2["offered"]
 
 
-def test_matrix_cell_abort_is_isolated():
-    """A broken cell (unknown scenario here; a backend outage in prod)
-    records the structured abort_reason and the matrix CONTINUES — the
-    PR 12 abort machinery at cell granularity."""
+def test_matrix_failed_cell_fails_the_run():
+    """A broken cell (an unknown scenario here) fails the whole matrix:
+    no cell is recorded as aborted and no result comes back."""
     sys.path.insert(0, REPO)
     try:
         import bench
     finally:
         sys.path.pop(0)
-    out = bench.bench_scenario_matrix(
-        _matrix_args(scenario_matrix="no-such-scenario,steady",
-                     scenario_time_scale=0.25))
-    cells = out["scenario_matrix"]
-    assert cells[0]["scenario"] == "no-such-scenario"
-    assert cells[0]["abort_reason"] == "cell_failed"
-    assert "abort_detail" in cells[0] and "abort_config" in cells[0]
-    assert cells[1]["abort_reason"] is None
-    assert out["value"] is not None  # the healthy cell still reports
+    with pytest.raises(FileNotFoundError, match="no-such-scenario"):
+        bench.bench_scenario_matrix(
+            _matrix_args(scenario_matrix="no-such-scenario,steady",
+                         scenario_time_scale=0.25))
 
 
 def test_bench_diff_gates_scenario_cells_and_skips_aborted():
