@@ -1,0 +1,9 @@
+"""Median, over every window request that was matched, of the time from
+when it was due to its ``matched`` reply (ms, client clock)."""
+
+import numpy as np
+
+
+def read(run):
+    ms = run.match_ms[~np.isnan(run.match_ms)]
+    return float(np.percentile(ms, 50)) if len(ms) else None
