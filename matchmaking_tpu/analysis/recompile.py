@@ -171,8 +171,13 @@ class _JitSiteScanner(ast.NodeVisitor):
     visit_AsyncFunctionDef = _enter_fn
 
     def visit_Call(self, node: ast.Call) -> None:
+        target = None
         if _is_jit_expr(node.func) and node.args:
             target = node.args[0]
+        elif (dotted_name(node.func).endswith("named_jit")
+              and len(node.args) > 1):
+            target = node.args[1]  # named_jit(name, fn, **jit_kwargs)
+        if target is not None:
             if isinstance(target, ast.Lambda):
                 self._check_captures(target, node.lineno,
                                      f"lambda@{node.lineno}")

@@ -70,6 +70,19 @@ _ADMIT_FIELDS = ("rating", "rd", "region", "mode", "threshold", "enqueue_t")
 INDEX_FIELDS = ("bidx_count", "bidx_min", "bidx_max", "bidx_rd")
 
 
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under ``name``: the compiled module is
+    ``jit_<name>`` and the host's launch events ``PjitFunction(<name>)``,
+    so a profile says which step ran. Steps are named after the attribute
+    that holds them; a lambda or ``functools.partial`` would otherwise
+    trace as ``jit__lambda_`` / ``jit__unknown``."""
+    def step(*args):
+        return fn(*args)
+
+    step.__name__ = step.__qualname__ = name
+    return jax.jit(step, **jit_kwargs)
+
+
 def unpack_batch(packed) -> dict[str, Any]:
     """f32[8, B] (see pool.PACKED_ROWS) → the batch dict the kernels use.
     One packed array = one H2D transfer per window instead of 8."""
@@ -335,20 +348,27 @@ class KernelSet:
             step = self._search_step
         self._step_impl = step
         if bucketed:
-            self.admit = jax.jit(self._admit_indexed, donate_argnums=0)
-            self.evict = jax.jit(self._evict_indexed, donate_argnums=0)
-            self.search_step = jax.jit(
+            self.admit = named_jit("admit", self._admit_indexed,
+                                   donate_argnums=0)
+            self.evict = named_jit("evict", self._evict_indexed,
+                                   donate_argnums=0)
+            self.search_step = named_jit(
+                "search_step",
                 lambda pool, batch, now: self._search_step_bucketed(
                     pool, batch, now)[:4], donate_argnums=0)
-            self.admit_packed = jax.jit(
+            self.admit_packed = named_jit(
+                "admit_packed",
                 lambda pool, packed: self._admit_indexed(
                     pool, unpack_batch(packed)), donate_argnums=0)
-            self.search_step_packed = jax.jit(
-                self._search_step_packed_bucketed, donate_argnums=0)
-            self.search_step_packed_nofilter = jax.jit(
+            self.search_step_packed = named_jit(
+                "search_step_packed", self._search_step_packed_bucketed,
+                donate_argnums=0)
+            self.search_step_packed_nofilter = named_jit(
+                "search_step_packed_nofilter",
                 functools.partial(self._search_step_packed_bucketed,
                                   skip_filters=True), donate_argnums=0)
-            self.search_step_packed_rescan = jax.jit(
+            self.search_step_packed_rescan = named_jit(
+                "search_step_packed_rescan",
                 self._rescan_step_packed_bucketed, donate_argnums=0)
             # Speculative-formation twin (ISSUE 16): the SAME rescan trace
             # jitted WITHOUT donation — the caller's input pool handle must
@@ -357,21 +377,23 @@ class KernelSet:
             # search_step_packed_rescan (donation changes buffer reuse, not
             # results), which is what the commit-equals-cold-rescan proof
             # leans on.
-            self.search_step_packed_spec = jax.jit(
-                self._rescan_step_packed_bucketed)
-            self.index_rebuild = jax.jit(self._index_rebuild,
-                                         donate_argnums=0)
+            self.search_step_packed_spec = named_jit(
+                "search_step_packed_spec", self._rescan_step_packed_bucketed)
+            self.index_rebuild = named_jit("index_rebuild",
+                                           self._index_rebuild,
+                                           donate_argnums=0)
             return
-        self.admit = jax.jit(self._admit, donate_argnums=0)
-        self.evict = jax.jit(self._evict, donate_argnums=0)
-        self.search_step = jax.jit(step, donate_argnums=0)
+        self.admit = named_jit("admit", self._admit, donate_argnums=0)
+        self.evict = named_jit("evict", self._evict, donate_argnums=0)
+        self.search_step = named_jit("search_step", step, donate_argnums=0)
         # Packed I/O variants: one f32[8,B] in, one f32[3,B] out — a single
         # H2D and a single D2H transfer per window.
-        self.admit_packed = jax.jit(
+        self.admit_packed = named_jit(
+            "admit_packed",
             lambda pool, packed: self._admit(pool, unpack_batch(packed)),
             donate_argnums=0)
-        self.search_step_packed = jax.jit(self._search_step_packed,
-                                          donate_argnums=0)
+        self.search_step_packed = named_jit(
+            "search_step_packed", self._search_step_packed, donate_argnums=0)
         # All-ANY-window variant: identical outputs when no window lane
         # carries a region/mode constraint (see _score_block); ~40% fewer
         # per-cell mask ops in the dominant score scan. The engine selects
@@ -379,17 +401,19 @@ class KernelSet:
         # deployment that must never pay that stall mid-serving sets
         # EngineConfig.warm_start, which compiles BOTH variants for every
         # bucket at app start (TpuEngine.warmup).
-        self.search_step_packed_nofilter = jax.jit(
+        self.search_step_packed_nofilter = named_jit(
+            "search_step_packed_nofilter",
             functools.partial(self._search_step_packed, skip_filters=True),
             donate_argnums=0)
         # Rescan variant: NO admission, lane validity gated by the
         # device-side active flag (see _rescan_step). What makes rescans
         # overlap in-flight windows AND span multiple chunks safely.
-        self.search_step_packed_rescan = jax.jit(
-            self._search_step_packed_rescan, donate_argnums=0)
+        self.search_step_packed_rescan = named_jit(
+            "search_step_packed_rescan", self._search_step_packed_rescan,
+            donate_argnums=0)
         # Non-donated speculative twin — see the bucketed branch note.
-        self.search_step_packed_spec = jax.jit(
-            self._search_step_packed_rescan)
+        self.search_step_packed_spec = named_jit(
+            "search_step_packed_spec", self._search_step_packed_rescan)
 
     def _search_step_packed(self, pool, packed, skip_filters: bool = False):
         """Packed window step: batch rows per pool.PACKED_ROWS plus a 9th row
@@ -1221,7 +1245,7 @@ class QualityAccumKernel:
         self.n_wait = len(wait_edges) + 1  # + overflow
         self._r_edges = np.asarray(rating_edges, np.float32)
         self._w_edges = np.asarray(wait_edges, np.float32)
-        self.accum = jax.jit(self._accum)
+        self.accum = named_jit("quality_accum", self._accum)
 
     def init_state(self) -> dict[str, jnp.ndarray]:
         r = self.n_rating

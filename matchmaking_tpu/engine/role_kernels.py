@@ -51,6 +51,7 @@ import numpy as np
 from matchmaking_tpu.engine.kernels import (
     _ADMIT_FIELDS,
     _admit_block,
+    named_jit,
     unpack_batch,
 )
 from matchmaking_tpu.engine.teams import (
@@ -101,13 +102,16 @@ class RoleKernelSet(TeamKernelSet):
             sum(1 for r in role_slots if (1 << self._bit[r]) & s)
             for s in self._subsets)
         # Role-aware packed entries override the base jits.
-        self.admit_packed = jax.jit(
+        self.admit_packed = named_jit(
+            "role_admit_packed",
             lambda pool, packed: self._admit_roles(
                 pool, self._unpack(packed)[0]),
             donate_argnums=0)
-        self.search_step = jax.jit(self._search_step, donate_argnums=0)
-        self.search_step_packed = jax.jit(self._search_step_packed,
-                                          donate_argnums=0)
+        self.search_step = named_jit("role_search_step", self._search_step,
+                                     donate_argnums=0)
+        self.search_step_packed = named_jit(
+            "role_search_step_packed", self._search_step_packed,
+            donate_argnums=0)
 
     # ---- host helpers ------------------------------------------------------
 
@@ -328,7 +332,8 @@ class ShardedRoleKernelSet:
                      ("rating", "rd", "region", "mode", "threshold",
                       "enqueue_t", "active", "role_mask")}
         rep = P()
-        self.search_step_packed = jax.jit(
+        self.search_step_packed = named_jit(
+            "role_sharded_search_step_packed",
             jax.shard_map(self._step_shard, mesh=mesh,
                           in_specs=(pool_spec, rep),
                           out_specs=(pool_spec, rep), check_vma=False),
@@ -342,17 +347,20 @@ class ShardedRoleKernelSet:
                 team_size=team_size, role_slots=role_slots,
                 widen_per_sec=widen_per_sec, max_threshold=max_threshold,
                 max_matches=self.max_matches, rounds=rounds)
-            self.search_step_packed_ring = jax.jit(
+            self.search_step_packed_ring = named_jit(
+                "role_sharded_search_step_packed_ring",
                 jax.shard_map(self._step_shard_ring, mesh=mesh,
                               in_specs=(pool_spec, rep),
                               out_specs=(pool_spec, rep), check_vma=False),
                 donate_argnums=0)
-        self.admit_packed = jax.jit(
+        self.admit_packed = named_jit(
+            "role_sharded_admit_packed",
             jax.shard_map(self._admit_shard, mesh=mesh,
                           in_specs=(pool_spec, rep), out_specs=pool_spec,
                           check_vma=False),
             donate_argnums=0)
-        self.evict = jax.jit(
+        self.evict = named_jit(
+            "role_sharded_evict",
             jax.shard_map(self._evict_shard, mesh=mesh,
                           in_specs=(pool_spec, rep), out_specs=pool_spec,
                           check_vma=False),

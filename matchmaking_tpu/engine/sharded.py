@@ -44,6 +44,7 @@ from matchmaking_tpu.engine.kernels import (
     KernelSet,
     _effective_threshold,
     greedy_pair,
+    named_jit,
     unpack_batch,
 )
 
@@ -217,7 +218,8 @@ class ShardedKernelSet:
                       ("slot", "rating", "rd", "region", "mode", "threshold",
                        "enqueue_t", "valid")}
 
-        self.search_step = jax.jit(
+        self.search_step = named_jit(
+            "sharded_search_step",
             jax.shard_map(
                 self._search_step_shard, mesh=mesh,
                 in_specs=(pool_spec, batch_spec, rep),
@@ -226,13 +228,15 @@ class ShardedKernelSet:
             ),
             donate_argnums=0,
         )
-        self.admit = jax.jit(
+        self.admit = named_jit(
+            "sharded_admit",
             jax.shard_map(self._admit_shard, mesh=mesh,
                           in_specs=(pool_spec, batch_spec), out_specs=pool_spec,
                           check_vma=False),
             donate_argnums=0,
         )
-        self.evict = jax.jit(
+        self.evict = named_jit(
+            "sharded_evict",
             jax.shard_map(self._evict_shard, mesh=mesh,
                           in_specs=(pool_spec, rep), out_specs=pool_spec,
                           check_vma=False),
@@ -240,7 +244,8 @@ class ShardedKernelSet:
         )
         # Packed I/O variants (one replicated f32[9,B] in / f32[3,B] out —
         # single H2D/D2H transfer per window; see pool.PACKED_ROWS).
-        self.search_step_packed = jax.jit(
+        self.search_step_packed = named_jit(
+            "sharded_search_step_packed",
             jax.shard_map(
                 self._search_step_packed_shard, mesh=mesh,
                 in_specs=(pool_spec, rep), out_specs=(pool_spec, rep),
@@ -248,7 +253,8 @@ class ShardedKernelSet:
             ),
             donate_argnums=0,
         )
-        self.admit_packed = jax.jit(
+        self.admit_packed = named_jit(
+            "sharded_admit_packed",
             jax.shard_map(
                 lambda pool, packed: self._admit_shard(pool, unpack_batch(packed)),
                 mesh=mesh, in_specs=(pool_spec, rep), out_specs=pool_spec,
@@ -369,7 +375,8 @@ class ShardedKernelSet:
                          ("rating", "rd", "region", "mode", "threshold",
                           "enqueue_t", "active")}
             rep = P()
-            fn = jax.jit(
+            fn = named_jit(
+                f"sharded_bucket_step_k{k}",
                 jax.shard_map(
                     functools.partial(self._search_step_bucket_shard, k=k),
                     mesh=self.mesh, in_specs=(pool_spec, rep),

@@ -54,7 +54,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from matchmaking_tpu.engine.kernels import KernelSet, _effective_threshold
+from matchmaking_tpu.engine.kernels import (
+    KernelSet,
+    _effective_threshold,
+    named_jit,
+)
 
 _BIG_I32 = jnp.int32(1 << 30)
 _INF = jnp.float32(jnp.inf)
@@ -231,9 +235,11 @@ class TeamKernelSet:
         self.admit = self._base.admit
         self.admit_packed = self._base.admit_packed
         self.evict = self._base.evict
-        self.search_step = jax.jit(self._search_step, donate_argnums=0)
-        self.search_step_packed = jax.jit(self._search_step_packed,
-                                          donate_argnums=0)
+        self.search_step = named_jit("team_search_step", self._search_step,
+                                     donate_argnums=0)
+        self.search_step_packed = named_jit(
+            "team_search_step_packed", self._search_step_packed,
+            donate_argnums=0)
 
     def _search_step_packed(self, pool, packed):
         """Packed team step: f32[9,B] in (see pool.PACKED_ROWS + now row),
@@ -436,7 +442,8 @@ class ShardedTeamKernelSet:
                      ("rating", "rd", "region", "mode", "threshold",
                       "enqueue_t", "active")}
         rep = P()
-        self.search_step_packed = jax.jit(
+        self.search_step_packed = named_jit(
+            "team_sharded_search_step_packed",
             jax.shard_map(self._step_shard, mesh=mesh,
                           in_specs=(pool_spec, rep),
                           out_specs=(pool_spec, rep), check_vma=False),
@@ -455,17 +462,20 @@ class ShardedTeamKernelSet:
                 team_size=team_size, widen_per_sec=widen_per_sec,
                 max_threshold=max_threshold, max_matches=self.max_matches,
                 rounds=rounds)
-            self.search_step_packed_ring = jax.jit(
+            self.search_step_packed_ring = named_jit(
+                "team_sharded_search_step_packed_ring",
                 jax.shard_map(self._step_shard_ring, mesh=mesh,
                               in_specs=(pool_spec, rep),
                               out_specs=(pool_spec, rep), check_vma=False),
                 donate_argnums=0)
-        self.admit_packed = jax.jit(
+        self.admit_packed = named_jit(
+            "team_sharded_admit_packed",
             jax.shard_map(self._admit_shard, mesh=mesh,
                           in_specs=(pool_spec, rep), out_specs=pool_spec,
                           check_vma=False),
             donate_argnums=0)
-        self.evict = jax.jit(
+        self.evict = named_jit(
+            "team_sharded_evict",
             jax.shard_map(self._evict_shard, mesh=mesh,
                           in_specs=(pool_spec, rep), out_specs=pool_spec,
                           check_vma=False),

@@ -68,6 +68,7 @@ from matchmaking_tpu.engine.kernels import (
     KernelSet,
     QualityAccumKernel,
     kernel_set,
+    named_jit,
 )
 from matchmaking_tpu.engine.quality import (
     HostQualityAccum,
@@ -82,8 +83,14 @@ from matchmaking_tpu.service.contract import (
     new_match_id,
     new_match_ids,
 )
+from matchmaking_tpu.utils.trace import stage
 
 logger = logging.getLogger(__name__)
+
+
+def _stack(*xs: Any) -> Any:
+    """K windows' results as one array: one device-to-host copy."""
+    return jnp.stack(xs)
 
 
 def _copy_async(h: Any) -> None:
@@ -479,6 +486,9 @@ class TpuEngine(Engine):
             "turnaround_s": 0.0, # dispatch → finalized (device + collect)
             "dedupe_s": 0.0, "alloc_s": 0.0, "pack_s": 0.0,
             "h2d_s": 0.0, "jit_s": 0.0,
+            # collect_ready host time: readiness poll, device-to-host
+            # materialisation and finalize (empty polls included)
+            "collect_s": 0.0,
         }
         #: Device-utilization accounting (ISSUE 6): monotone busy/idle
         #: second counters — busy while >= 1 window is dispatched-but-
@@ -618,7 +628,7 @@ class TpuEngine(Engine):
             fkey = (len(handles),) + key
             fn = self._stack_fns.get(fkey)
             if fn is None:
-                fn = jax.jit(lambda *xs: jnp.stack(xs))
+                fn = named_jit("readback_stack", _stack)
                 self._stack_fns[fkey] = fn
             g.stacked = fn(*handles)
             _copy_async(g.stacked)
@@ -737,37 +747,38 @@ class TpuEngine(Engine):
         assert not self._team_device and self._team_delegate is None, (
             "columnar path is 1v1-only (team/role queues use the object API)"
         )
-        t_start = time.perf_counter()
-        pending = _Pending(token=self._next_token, created=t_start)
-        pending.marks.append(("dispatch", time.time()))
-        pending.columnar = empty_columnar_outcome()
-        self._next_token += 1
+        token = self._next_token
+        with stage("dispatch", self.spans, token=token):
+            pending = _Pending(token=token, created=time.perf_counter())
+            pending.marks.append(("dispatch", time.time()))
+            pending.columnar = empty_columnar_outcome()
+            self._next_token += 1
 
-        ids = cols.ids.tolist()
-        waiting = self.pool._slot_of
-        _t = time.perf_counter()
-        if len(set(ids)) == len(ids):  # common case: no intra-window dups
-            keep = np.fromiter((i not in waiting for i in ids), bool, len(ids))
-        else:
-            local: set[str] = set()
-            keep = np.empty(len(ids), bool)
-            for j, pid in enumerate(ids):
-                keep[j] = pid not in waiting and pid not in local
-                if keep[j]:
-                    local.add(pid)
-        if not keep.all():
-            cols = cols.take(keep)
-        self.spans["dedupe_s"] += time.perf_counter() - _t
+            ids = cols.ids.tolist()
+            waiting = self.pool._slot_of
+            with stage("dedupe", self.spans, token=token):
+                if len(set(ids)) == len(ids):  # common case: no dups
+                    keep = np.fromiter((i not in waiting for i in ids), bool,
+                                       len(ids))
+                else:
+                    local: set[str] = set()
+                    keep = np.empty(len(ids), bool)
+                    for j, pid in enumerate(ids):
+                        keep[j] = pid not in waiting and pid not in local
+                        if keep[j]:
+                            local.add(pid)
+                if not keep.all():
+                    cols = cols.take(keep)
 
-        if len(cols):
-            self._pool_mutated()  # admission + donating step ahead
-        max_bucket = self.buckets[-1]
-        for start in range(0, len(cols), max_bucket):
-            self._dispatch_cols(cols.slice(start, start + max_bucket), now, pending)
-        self._submit(pending)
-        self.spans["requests"] += len(cols)
-        self.spans["dispatch_s"] += time.perf_counter() - t_start
-        return pending.token
+            if len(cols):
+                self._pool_mutated()  # admission + donating step ahead
+            max_bucket = self.buckets[-1]
+            for start in range(0, len(cols), max_bucket):
+                self._dispatch_cols(cols.slice(start, start + max_bucket),
+                                    now, pending)
+            self._submit(pending)
+            self.spans["requests"] += len(cols)
+        return token
 
     def rescan_async(self, max_window: int, now: float) -> int | None:
         """Re-submit the longest-waiting players as a search window so that
@@ -1183,28 +1194,26 @@ class TpuEngine(Engine):
             cols = cols.slice(0, free)
             if not len(cols):
                 return
-        _t = time.perf_counter()
-        slots = self.pool.allocate_columns(cols)
-        self.spans["alloc_s"] += time.perf_counter() - _t
+        token = pending.token
+        with stage("alloc", self.spans, token=token):
+            slots = self.pool.allocate_columns(cols)
         bucket = self._bucket_for(len(cols))
         t0 = self._rel_base(now)
-        _t = time.perf_counter()
-        batch = self.pool.batch_arrays_cols(cols, slots, bucket, t0)
-        packed = pack_batch(batch, now - t0)
-        self.spans["pack_s"] += time.perf_counter() - _t
-        _t = time.perf_counter()
-        packed_dev = jnp.asarray(packed)
-        self.spans["h2d_s"] += time.perf_counter() - _t
-        pending.marks.append(("h2d", time.time()))
-        _t = time.perf_counter()
-        self._dev_pool, out = self._step_fn(batch)(
-            self._dev_pool, packed_dev
-        )
-        self.spans["jit_s"] += time.perf_counter() - _t
-        pending.marks.append(("formation_bucketed"
-                              if self._last_step_bucketed
-                              else "device_step", time.time()))
-        self._quality_accum_dispatch(out, now)
+        with stage("pack", self.spans, token=token):
+            batch = self.pool.batch_arrays_cols(cols, slots, bucket, t0)
+            packed = pack_batch(batch, now - t0)
+        with stage("h2d", self.spans, token=token):
+            packed_dev = jnp.asarray(packed)
+            pending.marks.append(("h2d", time.time()))
+        with stage("launch", self.spans, "jit_s", token=token):
+            self._dev_pool, out = self._step_fn(batch)(
+                self._dev_pool, packed_dev
+            )
+            pending.marks.append(("formation_bucketed"
+                                  if self._last_step_bucketed
+                                  else "device_step", time.time()))
+        with stage("quality_accum", token=token):
+            self._quality_accum_dispatch(out, now)
         self.util["lanes_valid"] += len(cols)
         self.util["lanes_padded"] += bucket
         pending.chunks.append(((cols, slots), (out,), now))
@@ -1241,7 +1250,9 @@ class TpuEngine(Engine):
             "device_idle_s": round(idle, 6),
             "idle_fraction": round(idle / max(1e-9, busy + idle), 6),
             "h2d_s": round(self.spans["h2d_s"], 6),
-            "device_step_s": round(self.spans["jit_s"], 6),
+            # host time of the step's launch (the device time is in a
+            # profiler capture, by step name)
+            "launch_s": round(self.spans["jit_s"], 6),
             "readback_s": round(self.util["readback_s"], 6),
             "windows": self.spans["windows"],
             "lanes_valid": lanes_valid,
@@ -1423,18 +1434,24 @@ class TpuEngine(Engine):
         FIFO — a ready window behind an unfinished one waits its turn).
         Columnar windows yield ColumnarOutcome; object windows SearchOutcome."""
         done: list[tuple[int, SearchOutcome | ColumnarOutcome]] = []
-        if self._done_early:
-            done, self._done_early = self._done_early, []
-        if self._rb_k > 1:
-            self._rb_seal_stale()
-        while self._pending and self._is_ready(self._pending[0]):
-            pending = self._pending.popleft()
+        with stage("collect", self.spans):
+            if self._done_early:
+                done, self._done_early = self._done_early, []
+            if self._rb_k > 1:
+                self._rb_seal_stale()
+            while self._pending and self._is_ready(self._pending[0]):
+                done.append(self._collect_one(self._pending.popleft()))
+        return done
+
+    def _collect_one(self, pending: _Pending
+                     ) -> tuple[int, SearchOutcome | ColumnarOutcome]:
+        """Materialize and finalize one landed window (``mm.finalize``)."""
+        with stage("finalize", token=pending.token):
             self._fetch(pending)
             self._finalize(pending)
-            done.append((pending.token,
-                         pending.columnar if pending.columnar is not None
-                         else pending.outcome))
-        return done
+        return (pending.token,
+                pending.columnar if pending.columnar is not None
+                else pending.outcome)
 
     def flush(self) -> list[tuple[int, SearchOutcome | ColumnarOutcome]]:
         """Block until every in-flight window is collected and finalized."""
@@ -1444,12 +1461,7 @@ class TpuEngine(Engine):
         if self._rb_k > 1:
             self._rb_seal_stale(force=True)
         while self._pending:
-            pending = self._pending.popleft()
-            self._fetch(pending)
-            self._finalize(pending)
-            done.append((pending.token,
-                         pending.columnar if pending.columnar is not None
-                         else pending.outcome))
+            done.append(self._collect_one(self._pending.popleft()))
         # Every window is collected — refresh the quality snapshot so
         # drain/checkpoint/tests read exact totals (flush blocks anyway).
         self._quality_force_sync()

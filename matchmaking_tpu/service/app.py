@@ -47,7 +47,7 @@ from matchmaking_tpu.service.overload import (
     AdmissionController,
     deadline_of,
 )
-from matchmaking_tpu.service.attribution import Attribution
+from matchmaking_tpu.service.attribution import WAIT, Attribution
 from matchmaking_tpu.service.ingress import (
     IngressShards,
     ShardedRecent,
@@ -58,7 +58,13 @@ from matchmaking_tpu.engine.quality import QualitySpec
 from matchmaking_tpu.utils.chaos import ChaosState
 from matchmaking_tpu.utils.metrics import Metrics
 from matchmaking_tpu.utils.timeseries import SloMonitor, TelemetryRing
-from matchmaking_tpu.utils.trace import EventLog, FlightRecorder, TraceContext
+from matchmaking_tpu.utils.trace import (
+    EventLog,
+    FlightRecorder,
+    ProcessHooks,
+    TraceContext,
+    stage,
+)
 
 log = logging.getLogger(__name__)
 
@@ -1309,7 +1315,7 @@ class _QueueRuntime:
             # measured where it is spent — the batched twin records one
             # span per burst; this records one per delivery, so the
             # consume-share comparison across the two configs is honest.
-            self.app.attribution.observe_ingest(
+            self.app.attribution.observe_site(
                 self.queue_cfg.name, "consume",
                 time.time() - received_at, 1)
             return
@@ -1363,44 +1369,48 @@ class _QueueRuntime:
             return
         received_at = time.time()
         t_burst = time.perf_counter()  # monotonic twin: the ingest spans
-        ac = self.admission
-        # Window-granular admission's pre-checks (ISSUE 9), ONE pass over
-        # the burst (_inline_ingress guarantees batch_admission here):
-        # per-row pre_decide logic in burst order, amortized to one call.
-        decisions = (ac.pre_decide_batch(deliveries, received_at)
-                     if ac is not None else None)
-        live: list[Delivery] = []
-        for idx, delivery in enumerate(deliveries):
-            tr = self._trace(delivery)
-            if tr is not None:
-                tr.mark("consume", received_at)
-            if decisions is not None:
-                decision = decisions[idx]
+        # Spans never cover an await: the pre-checks here, the hand-off
+        # after the decode below (whose native call has its own span).
+        with stage("ingest", rows=len(deliveries)):
+            ac = self.admission
+            # Window-granular admission's pre-checks, ONE pass
+            # over the burst (_inline_ingress guarantees batch_admission
+            # here): per-row pre_decide logic in burst order, amortized to
+            # one call.
+            decisions = (ac.pre_decide_batch(deliveries, received_at)
+                         if ac is not None else None)
+            live: list[Delivery] = []
+            for idx, delivery in enumerate(deliveries):
+                tr = self._trace(delivery)
                 if tr is not None:
-                    tr.tier = delivery.tier
-                if decision is EXPIRED:
-                    self._expire_delivery(delivery, received_at)
-                    continue
-                if decision is not ADMIT:  # draining
-                    self._shed_delivery(delivery)
-                    continue
-            headers = delivery.properties.headers
-            first = headers.setdefault("x-first-received", received_at)
-            try:
-                delivery.first_received = float(first)
-            except (TypeError, ValueError):
-                delivery.first_received = received_at
-            if tr is not None:
-                # Same mark vocabulary as the per-delivery inline path so
-                # the trace classes are stable across configs; the burst
-                # handler's real cost is measured ONCE per burst into the
-                # `consume`/`decode` ingest categories instead of being
-                # smeared N× across member traces.
-                tr.mark("middleware", received_at)
-                tr.mark("batch", received_at)
-            delivery.arrival = self._arrival_seq
-            self._arrival_seq += 1
-            live.append(delivery)
+                    tr.mark("consume", received_at)
+                if decisions is not None:
+                    decision = decisions[idx]
+                    if tr is not None:
+                        tr.tier = delivery.tier
+                    if decision is EXPIRED:
+                        self._expire_delivery(delivery, received_at)
+                        continue
+                    if decision is not ADMIT:  # draining
+                        self._shed_delivery(delivery)
+                        continue
+                headers = delivery.properties.headers
+                first = headers.setdefault("x-first-received", received_at)
+                try:
+                    delivery.first_received = float(first)
+                except (TypeError, ValueError):
+                    delivery.first_received = received_at
+                if tr is not None:
+                    # Same mark vocabulary as the per-delivery inline path
+                    # so the trace classes are stable across configs; the
+                    # burst handler's real cost is measured ONCE per burst
+                    # into the `consume`/`decode` site categories instead
+                    # of being smeared N× across member traces.
+                    tr.mark("middleware", received_at)
+                    tr.mark("batch", received_at)
+                delivery.arrival = self._arrival_seq
+                self._arrival_seq += 1
+                live.append(delivery)
         if not live:
             return
         from matchmaking_tpu.native import codec
@@ -1417,16 +1427,17 @@ class _QueueRuntime:
             decode_s = time.perf_counter() - t_dec
             for delivery, counter, code, reason in rejects:
                 self._reject_delivery(delivery, counter, code, reason)
-            self.app.attribution.observe_ingest(
+            self.app.attribution.observe_site(
                 self.queue_cfg.name, "decode", decode_s,
                 len(live) + len(rejects))
-        self.batcher.submit_many([(None, d) for d in live])
+        with stage("ingest", rows=len(live)):
+            self.batcher.submit_many([(None, d) for d in live])
         # Monotonic throughout (perf_counter — a wall-clock step must not
         # produce a negative span the observe guard would silently drop);
         # at ingress_shards>1 the decode await can suspend, so decode_s
         # may include other tasks' loop time — noise, bounded by the
         # burst cadence, and identical across the A/B configs.
-        self.app.attribution.observe_ingest(
+        self.app.attribution.observe_site(
             self.queue_cfg.name, "consume",
             max(0.0, (time.perf_counter() - t_burst) - decode_s),
             len(deliveries))
@@ -1702,224 +1713,228 @@ class _QueueRuntime:
         from matchmaking_tpu.service.contract import RequestColumns
 
         now = time.time()
-        self._prune_recent(now)
-        if self._batch_admission:
-            # Admission ladder once per window, before decode — a shed
-            # request costs no decode work, exactly like the per-delivery
-            # flow (which also shed pre-decode).
-            dropped = self._admission_cut(deliveries, now)
-            if dropped:
-                deliveries = [d for d in deliveries
-                              if d.delivery_tag not in dropped]
-                if not deliveries:
-                    return
-        # Consume-time decoded windows (ISSUE 12): when EVERY lane carries
-        # a burst-decoded row reference (Delivery.row, set by the ingress
-        # shard workers), the flush decode is skipped entirely and the
-        # column assembly below gathers from the burst columns. A MIXED
-        # window (redeliveries consumed through the per-delivery fault
-        # path have no row) re-decodes wholesale — rare, and correct by
-        # construction (the body is unchanged).
-        pre = all(d.row is not None for d in deliveries)
-        native = None
-        t_dec = time.perf_counter()
-        if not pre:
-            bodies = [bytes(d.body) for d in deliveries]
-            native = codec.decode_batch(bodies) if codec.available() else None
+        # The cut to the hand-off: everything up to the first await.
+        with stage("flush", rows=len(deliveries)):
+            self._prune_recent(now)
+            if self._batch_admission:
+                # Admission ladder once per window, before decode — a shed
+                # request costs no decode work, exactly like the per-delivery
+                # flow (which also shed pre-decode).
+                dropped = self._admission_cut(deliveries, now)
+                if dropped:
+                    deliveries = [d for d in deliveries
+                                  if d.delivery_tag not in dropped]
+                    if not deliveries:
+                        return
+            # Consume-time decoded windows: when EVERY lane carries
+            # a burst-decoded row reference (Delivery.row, set by the ingress
+            # shard workers), the flush decode is skipped entirely and the
+            # column assembly below gathers from the burst columns. A MIXED
+            # window (redeliveries consumed through the per-delivery fault
+            # path have no row) re-decodes wholesale — rare, and correct by
+            # construction (the body is unchanged).
+            pre = all(d.row is not None for d in deliveries)
+            native = None
+            t_dec = time.perf_counter()
+            if not pre:
+                bodies = [bytes(d.body) for d in deliveries]
+                native = (codec.decode_batch(bodies) if codec.available()
+                          else None)
 
-        traced = any(d.trace is not None for d in deliveries)
-        if traced:
-            for d in deliveries:
-                if d.trace is not None:
-                    d.trace.mark("flush", now)
+            traced = any(d.trace is not None for d in deliveries)
+            if traced:
+                for d in deliveries:
+                    if d.trace is not None:
+                        d.trace.mark("flush", now)
 
-        # Row resolution: native-OK rows stay columnar end to end; only
-        # NEEDS_PYTHON rows materialize a SearchRequest, and only
-        # malformed rows pay a response here. ``rows``: (source index,
-        # player id, fallback request or None).
-        if native is not None:
-            ids_n, rating_n, rd_n, thr_n, regions_n, modes_n, status_n = native
-            status_l = status_n.tolist()
-        rows: list[tuple[int, str, SearchRequest | None]] = []
-        if pre:
-            # Burst-decoded: every row is valid (malformed rows settled at
-            # consume); the pid column reads straight out of the burst.
-            for i, delivery in enumerate(deliveries):
-                burst, j = delivery.row
-                rows.append((i, burst.ids[j], None))
-        else:
-            for i, delivery in enumerate(deliveries):
-                st = (int(status_l[i]) if native is not None
-                      else codec.NEEDS_PYTHON)
-                if st == codec.OK:
-                    rows.append((i, ids_n[i], None))
-                    continue
-                if st != codec.NEEDS_PYTHON:
-                    self._reject_delivery(delivery, "rejected_by_middleware",
-                                          codec.error_code(st),
-                                          "malformed payload")
-                    continue
-                # Python fallback (codec unavailable or NEEDS_PYTHON row).
-                req = self._decode_or_reject(delivery, now)
-                if req is None:
-                    continue
-                if req.party_size > 1:
-                    # 1v1 queue: parties are unservable (oracle semantics).
-                    self._reject_delivery(
-                        delivery, "rejected_by_engine",
-                        "party_not_supported",
-                        "engine rejected request: party_not_supported")
-                    continue
-                rows.append((i, req.id, req))
-            # Flush-time decode accounting (the consume_batch=off twin of
-            # the burst-decode observation — same category, same meaning).
-            self.app.attribution.observe_ingest(
-                self.queue_cfg.name, "decode",
-                time.perf_counter() - t_dec, len(deliveries))
-        if traced:
-            for src, pid, _req in rows:
-                tr = deliveries[src].trace
-                if tr is not None:
-                    tr.player_id = pid
-                    tr.tier = deliveries[src].tier
-
-        # Batch dedup probe (at-least-once terminal replay) + deadline
-        # check #2 (batch formation). Terminal replay BEFORE the deadline
-        # check — see the object-path twin: "matched" must never be
-        # followed by a contradictory post-deadline "timeout".
-        recent = self._recent
-        check_deadline = self.admission is not None
-        keep: list[tuple[int, str, SearchRequest | None]] = []
-        for src, pid, req in rows:
-            delivery = deliveries[src]
-            cached = recent.get(pid)
-            if cached is not None and cached[1] <= now:
-                recent.pop(pid)  # expired: a genuine re-queue
-                cached = None
-            if cached is not None:
-                self.app.metrics.counters.inc("deduped_replays")
-                self._publish_body(delivery.properties.reply_to,
-                                   delivery.properties.correlation_id,
-                                   cached[0], trace=delivery.trace)
-                self._ack(delivery)
-                if delivery.trace is not None:
-                    delivery.trace.mark("dedup_replay")
-                    self._settle_trace(delivery, "deduped")
-            elif check_deadline and self._deadline_expired(delivery, now):
-                # Columnar twin of deadline check #2 — after decode, so
-                # the timeout quotes the player id.
-                self._expire_delivery(delivery, now, player_id=pid)
+            # Row resolution: native-OK rows stay columnar end to end; only
+            # NEEDS_PYTHON rows materialize a SearchRequest, and only
+            # malformed rows pay a response here. ``rows``: (source index,
+            # player id, fallback request or None).
+            if native is not None:
+                (ids_n, rating_n, rd_n, thr_n, regions_n, modes_n,
+                 status_n) = native
+                status_l = status_n.tolist()
+            rows: list[tuple[int, str, SearchRequest | None]] = []
+            if pre:
+                # Burst-decoded: every row is valid (malformed rows settled at
+                # consume); the pid column reads straight out of the burst.
+                for i, delivery in enumerate(deliveries):
+                    burst, j = delivery.row
+                    rows.append((i, burst.ids[j], None))
             else:
-                keep.append((src, pid, req))
-        if not keep:
-            return
+                for i, delivery in enumerate(deliveries):
+                    st = (int(status_l[i]) if native is not None
+                          else codec.NEEDS_PYTHON)
+                    if st == codec.OK:
+                        rows.append((i, ids_n[i], None))
+                        continue
+                    if st != codec.NEEDS_PYTHON:
+                        self._reject_delivery(
+                            delivery, "rejected_by_middleware",
+                            codec.error_code(st), "malformed payload")
+                        continue
+                    # Python fallback (codec unavailable or NEEDS_PYTHON row).
+                    req = self._decode_or_reject(delivery, now)
+                    if req is None:
+                        continue
+                    if req.party_size > 1:
+                        # 1v1 queue: parties are unservable (oracle semantics).
+                        self._reject_delivery(
+                            delivery, "rejected_by_engine",
+                            "party_not_supported",
+                            "engine rejected request: party_not_supported")
+                        continue
+                    rows.append((i, req.id, req))
+                # Flush-time decode accounting (the consume_batch=off twin of
+                # the burst-decode observation — same category, same meaning).
+                self.app.attribution.observe_site(
+                    self.queue_cfg.name, "decode",
+                    time.perf_counter() - t_dec, len(deliveries))
+            if traced:
+                for src, pid, _req in rows:
+                    tr = deliveries[src].trace
+                    if tr is not None:
+                        tr.player_id = pid
+                        tr.tier = deliveries[src].tier
 
-        # QoS columns from the per-delivery caches (tier/deadline were
-        # parsed at most once, at admission) — mirrored into the pool for
-        # priority-aware eviction + the per-slot deadline sweep; None when
-        # overload control is off so the pool stores plain zeros.
-        stamp_qos = self.admission is not None
-        k = len(keep)
-        tier_col = (np.fromiter((deliveries[s].tier for s, _, _ in keep),
-                                np.int32, k) if stamp_qos else None)
-        dl_col = (np.fromiter(
-            (self._delivery_deadline(deliveries[s]) for s, _, _ in keep),
-            np.float64, k) if stamp_qos else None)
-        if self.app.cfg.overload.edf and stamp_qos and k > 1:
-            # EDF, flush side: the batcher already cut by (tier, deadline),
-            # but dedup/expiry/reject filtering just rewrote the lane set —
-            # re-establish the order so when this window splits into bucket
-            # CHUNKS, the near-deadline tier-0 lanes ride the first chunk.
-            # Stable (arange tiebreak): FIFO within equal keys. Gated on
-            # stamp_qos: edf without any admission knob leaves the QoS
-            # columns None, and every key is (0, inf) then anyway — the
-            # pre-PR lane sort was the same no-op.
-            dl_eff = np.where(dl_col > 0.0, dl_col, np.inf)
-            order = np.lexsort((np.arange(k), dl_eff, tier_col))
-            keep = [keep[j] for j in order.tolist()]
-            tier_col = tier_col[order]
-            dl_col = dl_col[order]
-
-        # Column assembly: pure numpy takes of the native decode arrays in
-        # the common all-native case; element-wise only for the rare
-        # fallback rows.
-        interner_r = self.engine.pool.regions.code
-        interner_m = self.engine.pool.modes.code
-        enq_col = np.fromiter(
-            (self._first_received(deliveries[s], now) for s, _, _ in keep),
-            np.float64, k)
-        reply_col = np.fromiter(
-            (deliveries[s].properties.reply_to for s, _, _ in keep),
-            object, k)
-        corr_col = np.fromiter(
-            (deliveries[s].properties.correlation_id for s, _, _ in keep),
-            object, k)
-        if pre:
-            # Merge shard/burst columns at the EDF cut (ISSUE 12): one
-            # vectorized take per (burst, column) in final window order.
-            # Region/mode are interned HERE — codes belong to the current
-            # engine incarnation (a revive between consume and flush
-            # rebuilds the interners).
-            g_ids, g_rating, g_rd, g_thr, g_reg, g_mode = gather_rows(
-                [deliveries[s].row for s, _, _ in keep])
-            cols = RequestColumns(
-                ids=g_ids,
-                rating=g_rating,
-                rd=g_rd,
-                region=np.fromiter(
-                    (0 if r == "" else interner_r(r)
-                     for r in g_reg.tolist()), np.int32, k),
-                mode=np.fromiter(
-                    (0 if m == "" else interner_m(m)
-                     for m in g_mode.tolist()), np.int32, k),
-                threshold=g_thr,
-                enqueued_at=enq_col, reply_to=reply_col,
-                correlation_id=corr_col, tier=tier_col, deadline=dl_col,
-            )
-        elif native is not None and all(req is None for _, _, req in keep):
-            sel = np.fromiter((s for s, _, _ in keep), np.int64, k)
-            cols = RequestColumns(
-                ids=ids_n[sel],
-                rating=rating_n[sel],
-                rd=rd_n[sel],
-                region=np.fromiter(
-                    (0 if r == "" else interner_r(r)
-                     for r in regions_n[sel].tolist()), np.int32, k),
-                mode=np.fromiter(
-                    (0 if m == "" else interner_m(m)
-                     for m in modes_n[sel].tolist()), np.int32, k),
-                threshold=thr_n[sel],
-                enqueued_at=enq_col, reply_to=reply_col,
-                correlation_id=corr_col, tier=tier_col, deadline=dl_col,
-            )
-        else:
-            rating_a = np.empty(k, np.float32)
-            rd_a = np.empty(k, np.float32)
-            thr_a = np.empty(k, np.float32)
-            reg_a = np.empty(k, np.int32)
-            mode_a = np.empty(k, np.int32)
-            for j, (s, _pid, req) in enumerate(keep):
-                if req is None:
-                    rating_a[j] = rating_n[s]
-                    rd_a[j] = rd_n[s]
-                    thr_a[j] = thr_n[s]
-                    r, m = regions_n[s], modes_n[s]
+            # Batch dedup probe (at-least-once terminal replay) + deadline
+            # check #2 (batch formation). Terminal replay BEFORE the deadline
+            # check — see the object-path twin: "matched" must never be
+            # followed by a contradictory post-deadline "timeout".
+            recent = self._recent
+            check_deadline = self.admission is not None
+            keep: list[tuple[int, str, SearchRequest | None]] = []
+            for src, pid, req in rows:
+                delivery = deliveries[src]
+                cached = recent.get(pid)
+                if cached is not None and cached[1] <= now:
+                    recent.pop(pid)  # expired: a genuine re-queue
+                    cached = None
+                if cached is not None:
+                    self.app.metrics.counters.inc("deduped_replays")
+                    self._publish_body(delivery.properties.reply_to,
+                                       delivery.properties.correlation_id,
+                                       cached[0], trace=delivery.trace)
+                    self._ack(delivery)
+                    if delivery.trace is not None:
+                        delivery.trace.mark("dedup_replay")
+                        self._settle_trace(delivery, "deduped")
+                elif check_deadline and self._deadline_expired(delivery, now):
+                    # Columnar twin of deadline check #2 — after decode, so
+                    # the timeout quotes the player id.
+                    self._expire_delivery(delivery, now, player_id=pid)
                 else:
-                    rating_a[j] = req.rating
-                    rd_a[j] = req.rating_deviation
-                    thr_a[j] = (np.nan if req.rating_threshold is None
-                                else req.rating_threshold)
-                    r = "" if req.region == "*" else req.region
-                    m = "" if req.game_mode == "*" else req.game_mode
-                reg_a[j] = 0 if r == "" else interner_r(r)
-                mode_a[j] = 0 if m == "" else interner_m(m)
-            cols = RequestColumns(
-                ids=np.fromiter((pid for _, pid, _ in keep), object, k),
-                rating=rating_a, rd=rd_a, region=reg_a, mode=mode_a,
-                threshold=thr_a, enqueued_at=enq_col, reply_to=reply_col,
-                correlation_id=corr_col, tier=tier_col, deadline=dl_col,
-            )
-        by_id = {pid: deliveries[s] for s, pid, _ in keep}
+                    keep.append((src, pid, req))
+            if not keep:
+                return
+
+            # QoS columns from the per-delivery caches (tier/deadline were
+            # parsed at most once, at admission) — mirrored into the pool for
+            # priority-aware eviction + the per-slot deadline sweep; None when
+            # overload control is off so the pool stores plain zeros.
+            stamp_qos = self.admission is not None
+            k = len(keep)
+            tier_col = (np.fromiter((deliveries[s].tier for s, _, _ in keep),
+                                    np.int32, k) if stamp_qos else None)
+            dl_col = (np.fromiter(
+                (self._delivery_deadline(deliveries[s]) for s, _, _ in keep),
+                np.float64, k) if stamp_qos else None)
+            if self.app.cfg.overload.edf and stamp_qos and k > 1:
+                # EDF, flush side: the batcher already cut by (tier, deadline),
+                # but dedup/expiry/reject filtering just rewrote the lane set —
+                # re-establish the order so when this window splits into bucket
+                # CHUNKS, the near-deadline tier-0 lanes ride the first chunk.
+                # Stable (arange tiebreak): FIFO within equal keys. Gated on
+                # stamp_qos: edf without any admission knob leaves the QoS
+                # columns None, and every key is (0, inf) then anyway: the
+                # sort is a no-op there.
+                dl_eff = np.where(dl_col > 0.0, dl_col, np.inf)
+                order = np.lexsort((np.arange(k), dl_eff, tier_col))
+                keep = [keep[j] for j in order.tolist()]
+                tier_col = tier_col[order]
+                dl_col = dl_col[order]
+
+            # Column assembly: pure numpy takes of the native decode arrays in
+            # the common all-native case; element-wise only for the rare
+            # fallback rows.
+            interner_r = self.engine.pool.regions.code
+            interner_m = self.engine.pool.modes.code
+            enq_col = np.fromiter(
+                (self._first_received(deliveries[s], now) for s, _, _ in keep),
+                np.float64, k)
+            reply_col = np.fromiter(
+                (deliveries[s].properties.reply_to for s, _, _ in keep),
+                object, k)
+            corr_col = np.fromiter(
+                (deliveries[s].properties.correlation_id for s, _, _ in keep),
+                object, k)
+            if pre:
+                # Merge shard/burst columns at the EDF cut: one
+                # vectorized take per (burst, column) in final window order.
+                # Region/mode are interned HERE — codes belong to the current
+                # engine incarnation (a revive between consume and flush
+                # rebuilds the interners).
+                g_ids, g_rating, g_rd, g_thr, g_reg, g_mode = gather_rows(
+                    [deliveries[s].row for s, _, _ in keep])
+                cols = RequestColumns(
+                    ids=g_ids,
+                    rating=g_rating,
+                    rd=g_rd,
+                    region=np.fromiter(
+                        (0 if r == "" else interner_r(r)
+                         for r in g_reg.tolist()), np.int32, k),
+                    mode=np.fromiter(
+                        (0 if m == "" else interner_m(m)
+                         for m in g_mode.tolist()), np.int32, k),
+                    threshold=g_thr,
+                    enqueued_at=enq_col, reply_to=reply_col,
+                    correlation_id=corr_col, tier=tier_col, deadline=dl_col,
+                )
+            elif native is not None and all(req is None for _, _, req in keep):
+                sel = np.fromiter((s for s, _, _ in keep), np.int64, k)
+                cols = RequestColumns(
+                    ids=ids_n[sel],
+                    rating=rating_n[sel],
+                    rd=rd_n[sel],
+                    region=np.fromiter(
+                        (0 if r == "" else interner_r(r)
+                         for r in regions_n[sel].tolist()), np.int32, k),
+                    mode=np.fromiter(
+                        (0 if m == "" else interner_m(m)
+                         for m in modes_n[sel].tolist()), np.int32, k),
+                    threshold=thr_n[sel],
+                    enqueued_at=enq_col, reply_to=reply_col,
+                    correlation_id=corr_col, tier=tier_col, deadline=dl_col,
+                )
+            else:
+                rating_a = np.empty(k, np.float32)
+                rd_a = np.empty(k, np.float32)
+                thr_a = np.empty(k, np.float32)
+                reg_a = np.empty(k, np.int32)
+                mode_a = np.empty(k, np.int32)
+                for j, (s, _pid, req) in enumerate(keep):
+                    if req is None:
+                        rating_a[j] = rating_n[s]
+                        rd_a[j] = rd_n[s]
+                        thr_a[j] = thr_n[s]
+                        r, m = regions_n[s], modes_n[s]
+                    else:
+                        rating_a[j] = req.rating
+                        rd_a[j] = req.rating_deviation
+                        thr_a[j] = (np.nan if req.rating_threshold is None
+                                    else req.rating_threshold)
+                        r = "" if req.region == "*" else req.region
+                        m = "" if req.game_mode == "*" else req.game_mode
+                    reg_a[j] = 0 if r == "" else interner_r(r)
+                    mode_a[j] = 0 if m == "" else interner_m(m)
+                cols = RequestColumns(
+                    ids=np.fromiter((pid for _, pid, _ in keep), object, k),
+                    rating=rating_a, rd=rd_a, region=reg_a, mode=mode_a,
+                    threshold=thr_a, enqueued_at=enq_col, reply_to=reply_col,
+                    correlation_id=corr_col, tier=tier_col, deadline=dl_col,
+                )
+            by_id = {pid: deliveries[s] for s, pid, _ in keep}
 
         if not self._pipelined:
             deliveries_in = [deliveries[s] for s, _, _ in keep]
@@ -1993,7 +2008,7 @@ class _QueueRuntime:
             for tok, out in outs:
                 self._merge_window_marks(tok, deliveries_in)
                 await self._handle_columnar_out(out, by_id, deliveries_in,
-                                                now)
+                                                now, tok)
             return
 
         # Pipelined path: dispatch without waiting; outcomes (publish + ack)
@@ -2347,7 +2362,8 @@ class _QueueRuntime:
             return
         try:
             if hasattr(out, "m_id_a"):
-                await self._handle_columnar_out(out, by_id, deliveries, now)
+                await self._handle_columnar_out(out, by_id, deliveries, now,
+                                                tok)
             else:
                 self._handle_object_out(out, deliveries, now)
         except Exception:
@@ -2382,7 +2398,7 @@ class _QueueRuntime:
     # settles: *deliveries
     async def _handle_columnar_out(self, out, by_id: dict[str, Delivery],
                                    deliveries: list[Delivery],
-                                   now: float) -> None:
+                                   now: float, token: int = -1) -> None:
         """Publish one collected window's outcome and ack its deliveries.
 
         Async settle (ISSUE 15): the rows are BUILT first — terminal
@@ -2399,68 +2415,80 @@ class _QueueRuntime:
         makes the publish-time commit non-empty, never unsafe (it covers
         strictly MORE records than write-ahead requires)."""
         m = self.app.metrics
-        trace_ids = self._trace_id_map(deliveries)
-        traces = self._trace_map(deliveries)
-        rows = self._build_columnar_rows(out, now, trace_ids=trace_ids,
-                                         traces=traces)
-        if self.queue_cfg.send_queued_ack and len(out.q_ids):
-            # Queued acks ride the batch path too (ISSUE 9): one native
-            # encode per window instead of an encode_response + publish
-            # per newly pooled player — and they share the matches'
-            # publish_batch call below.
-            import numpy as np
+        # Egress spans: one per window on each side of the journal
+        # commit's await; their sum is the window's `egress` site time.
+        egress = {"egress_s": 0.0}
+        with stage("egress", egress, token=token), \
+                stage("encode", token=token):
+            trace_ids = self._trace_id_map(deliveries)
+            traces = self._trace_map(deliveries)
+            rows = self._build_columnar_rows(
+                out, now, trace_ids=trace_ids, traces=traces)
+            if self.queue_cfg.send_queued_ack and len(out.q_ids):
+                # Queued acks ride the batch path too: one native
+                # encode per window instead of an encode_response + publish
+                # per newly pooled player — and they share the matches'
+                # publish_batch call below.
+                import numpy as np
 
-            from matchmaking_tpu.native import codec
+                from matchmaking_tpu.native import codec
 
-            metas = [(pid, by_id[pid]) for pid in out.q_ids.tolist()
-                     if pid in by_id]
-            if metas:
-                nq = len(metas)
-                bodies_q = None
-                if codec.available():
-                    bodies_q = codec.encode_simple_batch(
-                        np.full(nq, codec.KIND_QUEUED, np.int32),
-                        [pid for pid, _ in metas],
-                        np.zeros(nq, np.float64), None,
-                        [trace_ids.get(pid, "") for pid, _ in metas], None)
-                for j, (pid, d) in enumerate(metas):
-                    body = bodies_q[j] if bodies_q is not None else None
-                    if body is None:  # codec off or NEEDS_PYTHON row
-                        body = encode_response(SearchResponse(  # matchlint: ignore[perf] per-ROW fallback: codec off or NEEDS_PYTHON rows only
-                            status="queued", player_id=pid,
-                            trace_id=trace_ids.get(pid, "")))
-                    if d.trace is not None:
-                        d.trace.mark("encode")
-                    rows.append((d.properties.reply_to,
-                                 d.properties.correlation_id, body,
-                                 d.trace))
+                metas = [(pid, by_id[pid]) for pid in out.q_ids.tolist()
+                         if pid in by_id]
+                if metas:
+                    nq = len(metas)
+                    bodies_q = None
+                    if codec.available():
+                        bodies_q = codec.encode_simple_batch(
+                            np.full(nq, codec.KIND_QUEUED, np.int32),
+                            [pid for pid, _ in metas],
+                            np.zeros(nq, np.float64), None,
+                            [trace_ids.get(pid, "") for pid, _ in metas],
+                            None)
+                    for j, (pid, d) in enumerate(metas):
+                        body = (bodies_q[j] if bodies_q is not None
+                                else None)
+                        if body is None:  # codec off or NEEDS_PYTHON row
+                            body = encode_response(SearchResponse(  # matchlint: ignore[perf] per-ROW fallback: codec off or NEEDS_PYTHON rows only
+                                status="queued", player_id=pid,
+                                trace_id=trace_ids.get(pid, "")))
+                        if d.trace is not None:
+                            d.trace.mark("encode")
+                        rows.append((d.properties.reply_to,
+                                     d.properties.correlation_id, body,
+                                     d.trace))
         jnl = self.journal
         if jnl is not None and jnl.needs_commit:
             await asyncio.to_thread(jnl.commit)
-        if rows:
-            self._publish_batch(rows)
-        for pid, code in out.rejected:
-            m.counters.inc("rejected_by_engine")
-            d = by_id.get(pid)
-            if d is not None:
-                self._respond_error(d, code,
-                                    f"engine rejected request: {code}")
-        for d in deliveries:
-            self._ack(d)
-        if any(d.trace is not None for d in deliveries):
-            matched_ids = set(out.m_id_a.tolist()) | set(out.m_id_b.tolist())  # matchlint: ignore[perf] O(window matches) OUTCOME columns, traced windows only — not a pool scan
-            rejected_ids = {pid for pid, _ in out.rejected}
-            t_settle = time.time()
+        with stage("egress", egress, token=token):
+            if rows:
+                with stage("publish", token=token):
+                    self._publish_batch(rows)
+            for pid, code in out.rejected:
+                m.counters.inc("rejected_by_engine")
+                d = by_id.get(pid)
+                if d is not None:
+                    self._respond_error(d, code,
+                                        f"engine rejected request: {code}")
             for d in deliveries:
-                tr = d.trace
-                if tr is None:
-                    continue
-                status = ("matched" if tr.player_id in matched_ids else
-                          "rejected" if tr.player_id in rejected_ids else
-                          "queued")
-                self._settle_trace(d, status, t_settle)
-        m.counters.inc("windows")
-        m.counters.inc("requests_batched", len(deliveries))
+                self._ack(d)
+            if any(d.trace is not None for d in deliveries):
+                matched_ids = set(out.m_id_a.tolist()) | set(out.m_id_b.tolist())  # matchlint: ignore[perf] O(window matches) OUTCOME columns, traced windows only — not a pool scan
+                rejected_ids = {pid for pid, _ in out.rejected}
+                t_settle = time.time()
+                for d in deliveries:
+                    tr = d.trace
+                    if tr is None:
+                        continue
+                    status = ("matched" if tr.player_id in matched_ids else
+                              "rejected" if tr.player_id in rejected_ids else
+                              "queued")
+                    self._settle_trace(d, status, t_settle)
+            m.counters.inc("windows")
+            m.counters.inc("requests_batched", len(deliveries))
+        self.app.attribution.observe_site(
+            self.queue_cfg.name, "egress", egress["egress_s"],
+            len(rows))
 
     # settles: *deliveries
     def _handle_object_out(self, out, deliveries: list[Delivery],
@@ -2549,7 +2577,13 @@ class _QueueRuntime:
         stops → in-flight windows must still complete promptly). Supervised:
         a publish/revive failure on one tick must not kill the task — a dead
         collector means windows dispatched just before a traffic pause are
-        NEVER collected (matches unpublished, deliveries unacked)."""
+        NEVER collected (matches unpublished, deliveries unacked).
+
+        Each tick records how late its sleep woke beyond the time asked
+        for (site category ``loop_lag``): the time a ready callback waited
+        for the event loop."""
+        observe = self.app.attribution.observe_site
+        queue = self.queue_cfg.name
         while True:
             try:
                 if self.engine.inflight() > 0 or self._needs_revive:
@@ -2558,9 +2592,13 @@ class _QueueRuntime:
                         await self._collect_ready_locked(now)
                         if self._needs_revive and self.engine.inflight() == 0:
                             self._revive_locked(now)
-                    await asyncio.sleep(0.001)
+                    delay = 0.001
                 else:
-                    await asyncio.sleep(0.01)
+                    delay = 0.01
+                t = time.perf_counter()
+                await asyncio.sleep(delay)
+                observe(queue, "loop_lag",
+                        max(0.0, time.perf_counter() - t - delay), 1, WAIT)
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -3742,6 +3780,9 @@ class MatchmakingApp:
 
         self.incidents = IncidentRecorder(self, self.cfg.forensics)
         self._started = False
+        #: The loop this app's counters are attached to the process-wide
+        #: stall hooks on (utils/trace.ProcessHooks) while it runs.
+        self._hooks_loop: "asyncio.AbstractEventLoop | None" = None
         self._observability = None
         #: Elastic placement control plane (ISSUE 11; None = disabled).
         #: Built at start(): the controller needs the runtimes to bind
@@ -3869,7 +3910,16 @@ class MatchmakingApp:
                 self, host=self.cfg.metrics_host,
                 port=self.cfg.metrics_port)
             await self._observability.start()
+        self._hooks_loop = asyncio.get_running_loop()
+        ProcessHooks.attach(self.metrics.counters, self._hooks_loop)
         self._started = True
+
+    def _detach_hooks(self) -> None:
+        """Leave the process-wide stall hooks (the last app out removes
+        them)."""
+        if self._hooks_loop is not None:
+            ProcessHooks.detach(self.metrics.counters, self._hooks_loop)
+            self._hooks_loop = None
 
     def _boot_placement(self, index: int,
                         queue_cfg: QueueConfig) -> "tuple[int, ...] | None":
@@ -3922,6 +3972,7 @@ class MatchmakingApp:
             rt.abandon()
         self.broker.close()
         self._close_owned_net_hub()
+        self._detach_hooks()
         self._started = False
 
     async def stop(self) -> None:
@@ -3938,6 +3989,7 @@ class MatchmakingApp:
             await rt.close()
         self.broker.close()
         self._close_owned_net_hub()
+        self._detach_hooks()
         self._started = False
 
     async def drain(self, checkpoint_dir: str | None = None) -> dict[str, int]:
@@ -4029,6 +4081,7 @@ class MatchmakingApp:
             self._observability = None
         self.broker.close()
         self._close_owned_net_hub()
+        self._detach_hooks()
         self._started = False
         return counts
 

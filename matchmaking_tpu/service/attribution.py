@@ -226,7 +226,7 @@ class _RescanStats:
 class _QueueAttribution:
     __slots__ = ("categories", "work_s", "wait_s", "spans", "total_hist",
                  "statuses", "slo_good", "slo_total", "tiers", "rescan",
-                 "ingest")
+                 "sites")
 
     def __init__(self, buckets: tuple[float, ...]):
         self.categories: dict[str, _Category] = {}
@@ -239,17 +239,17 @@ class _QueueAttribution:
         self.slo_total = 0
         self.tiers: dict[int, _TierStats] = {}
         self.rescan: _RescanStats | None = None
-        #: Ingest-side WORK categories (ISSUE 12): ``consume`` (broker
-        #: consume machinery + admission pre-checks + batcher hand-off)
-        #: and ``decode`` (wire-body → columns, native or contract path),
-        #: measured DIRECTLY at the burst/window site — one observation
-        #: per burst, not one per trace — so the per-delivery cost is a
-        #: true wall-clock sum on both the batched and the per-delivery
-        #: ingress, comparable across the consume_batch on/off configs.
+        #: Site categories, measured DIRECTLY where the work happens —
+        #: one observation per burst, window or tick, not one per trace:
+        #: ``consume`` (broker consume machinery + admission pre-checks +
+        #: batcher hand-off) and ``decode`` (wire-body → columns) on
+        #: ingress, ``egress`` (building and publishing one
+        #: window's replies) and ``loop_lag`` (how late the collector's
+        #: sleep woke: the time a ready callback waited for the loop).
         #: Kept OUT of work_s/wait_s: those telescope to settled-trace
         #: spans exactly (the check.sh identity), and these spans overlap
         #: trace gaps that are already classified.
-        self.ingest: dict[str, _Category] = {}
+        self.sites: dict[str, _Category] = {}
 
 
 class Attribution:
@@ -324,19 +324,20 @@ class Attribution:
                 if good:
                     ts.slo_good += 1
 
-    def observe_ingest(self, queue: str, category: str, seconds: float,
-                       rows: int) -> None:
-        """Record one ingest-side work span (ISSUE 12): ``category`` is
-        ``"consume"`` or ``"decode"``, ``seconds`` the measured wall time
-        of one burst/window's worth of that work, ``rows`` the deliveries
-        it covered. Monotone counters, one call per burst — the 2×-down
-        acceptance gate reads the resulting per-category share."""
+    def observe_site(self, queue: str, category: str, seconds: float,
+                     rows: int, kind: str = WORK) -> None:
+        """Record one span measured at its site: ``category`` names it
+        (``consume``, ``decode``, ``egress``, ``loop_lag``), ``seconds``
+        is the measured wall time of one burst, window or tick of it,
+        ``rows`` the deliveries or replies it covered (1 for a tick).
+        Monotone counters, one call per site visit: a category's
+        ``total_s / traces`` is its mean per row."""
         if seconds < 0.0:
             return
         qa = self._queue(queue)
-        cat = qa.ingest.get(category)
+        cat = qa.sites.get(category)
         if cat is None:
-            cat = qa.ingest[category] = _Category(WORK, self.buckets)
+            cat = qa.sites[category] = _Category(kind, self.buckets)
         cat.gaps += 1
         cat.traces += max(0, rows)
         cat.total_s += seconds
@@ -408,12 +409,12 @@ class Attribution:
                 }
                 for name, cat in sorted(qa.categories.items())
             }
-            # Ingest categories (ISSUE 12): measured at the burst/window
-            # site, reported alongside the trace-derived ones with the
-            # same share denominator (the queue's settled span) so
-            # "consume/decode share" is directly comparable round over
-            # round and across the consume_batch on/off configs.
-            for name, cat in sorted(qa.ingest.items()):
+            # Site categories: measured where the work happens, reported
+            # alongside the trace-derived ones with the same share
+            # denominator (the queue's settled span) so "consume/decode
+            # share" is directly comparable round over round and across
+            # the consume_batch on/off configs.
+            for name, cat in sorted(qa.sites.items()):
                 cats[name] = {
                     "kind": cat.kind,
                     "gaps": cat.gaps,

@@ -49,6 +49,8 @@ from typing import TYPE_CHECKING, Any  # noqa: F401  (Any: reject tuples)
 
 import numpy as np
 
+from matchmaking_tpu.utils.trace import stage
+
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from matchmaking_tpu.service.broker import Delivery
 
@@ -206,7 +208,8 @@ class IngressShards:
         offsets = np.zeros(n + 1, np.int64)
         np.cumsum(np.fromiter((len(b) for b in bodies), np.int64, n),
                   out=offsets[1:])
-        native = codec.decode_batch_concat(b"".join(bodies), offsets)
+        with stage("decode", rows=n):
+            native = codec.decode_batch_concat(b"".join(bodies), offsets)
         rejects_i: list[tuple[int, str, str, str]] = []
         fallback: list[int] = []
         if native is None:

@@ -29,14 +29,232 @@ interleaved where they happen (chaos_drop, dedup_replay, oracle_step,
 reject). Window-level marks (dispatch..collect) are recorded once per
 engine window and merged into every member trace at settle time, so
 histogram counts for those stages are per-request attributions.
+
+Two more pieces put the host stages into a ``jax.profiler`` capture:
+
+- ``stage`` — a span named ``mm.<name>`` on the profiler's host timeline
+  (one ``TraceAnnotation``), plus its ``perf_counter`` duration added to a
+  counter. Spans are opened per burst, window or tick, never per request,
+  and never held across an ``await``.
+- ``ProcessHooks`` — the event loop's selector wait (``mm.loop_idle``,
+  counter ``loop_idle_s``) and the garbage collector's pauses (``mm.gc``,
+  counters ``gc_s`` / ``gc_pauses`` and per generation), installed once
+  per process while an app runs.
+
+One clock: the profiler stamps host events on the wall clock that
+``time.time()`` reads (a capture stores them relative to its
+``profile_start_time``), so the recorder's marks and the spans line up
+without conversion.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
+import logging
+import sys
+import threading
 import time
 from collections import deque
 from typing import Any, Iterable
+
+log = logging.getLogger(__name__)
+
+
+class _NoAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation`` in a process that
+    has not imported JAX (no profiler can be recording there)."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str, **args: Any) -> None:
+        pass
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return False
+
+    def __enter__(self) -> "_NoAnnotation":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+
+_annotation: Any = None
+
+
+def _annotation_type() -> Any:
+    """``jax.profiler.TraceAnnotation`` once JAX is loaded, else a no-op.
+    Never imports JAX itself: a host-only deployment does not pay for it."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return _NoAnnotation
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class stage:
+    """``with stage(name, sink, key, token=...):`` — one span ``mm.<name>``
+    on the profiler's host timeline, its ``args`` attached (the window's
+    ``token`` joins a window's spans across threads). With a ``sink``, the
+    span's ``perf_counter`` duration is added to ``sink[key]`` (``key``
+    defaults to ``name + "_s"``). With the profiler off no annotation is
+    made, so the cost is one enabled check and the counter update."""
+
+    __slots__ = ("_name", "_args", "_ann", "_sink", "_key", "_t")
+
+    def __init__(self, name: str, sink: "dict | None" = None,
+                 key: "str | None" = None, **args: Any) -> None:
+        self._name = name
+        self._args = args
+        self._sink = sink
+        self._key = key
+
+    def __enter__(self) -> "stage":
+        annotation = _annotation_type()
+        if annotation.is_enabled():
+            self._ann = annotation("mm." + self._name, **self._args)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self._t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if self._sink is not None:
+            key = self._key if self._key is not None else self._name + "_s"
+            self._sink[key] += time.perf_counter() - self._t
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+class ProcessHooks:
+    """The process-wide stall hooks, shared by every running app.
+
+    - ``loop_idle``: the event loop's selector ``select`` call is wrapped,
+      so the time the loop waits for I/O or a timer becomes the counter
+      ``loop_idle_s`` (a loop near 0 idle is saturated) and an
+      ``mm.loop_idle`` span.
+    - ``gc``: a ``gc.callbacks`` entry times each collection into
+      ``gc_s`` / ``gc_pauses`` and their ``_gen<n>`` splits, with an
+      ``mm.gc`` span.
+
+    ``attach(counters, loop)`` registers an app's counters (anything with
+    ``inc(name, value)``); ``detach`` removes them, and the hooks come out
+    when the last app on a loop (selector) or in the process (gc) leaves.
+    A loop with no selector to wrap (a non-selector event loop) is logged
+    once and counted without ``loop_idle``."""
+
+    _lock = threading.Lock()
+    #: loop → (its wrapped selector or None, the counters attached there)
+    _loops: dict = {}
+    #: every attached counters object, for the collector's pauses
+    _sinks: list = []
+    _gc_t = 0.0
+    _gc_ann: Any = None
+    _warned = False
+
+    @classmethod
+    def attach(cls, counters: Any, loop: Any) -> None:
+        with cls._lock:
+            if not cls._sinks:
+                gc.callbacks.append(cls._on_gc)
+            cls._sinks.append(counters)
+            entry = cls._loops.get(loop)
+            if entry is None:
+                selector = getattr(loop, "_selector", None)
+                select = getattr(selector, "select", None)
+                if select is None:
+                    if not cls._warned:
+                        cls._warned = True
+                        log.info("event loop %s has no selector to wrap; "
+                                 "loop_idle_s is not counted",
+                                 type(loop).__name__)
+                    entry = (None, [])
+                else:
+                    entry = (selector, [])
+                    selector.select = cls._wrap(select, entry[1])
+                cls._loops[loop] = entry
+            entry[1].append(counters)
+
+    @classmethod
+    def detach(cls, counters: Any, loop: Any) -> None:
+        with cls._lock:
+            if counters in cls._sinks:
+                cls._sinks.remove(counters)
+                if not cls._sinks and cls._on_gc in gc.callbacks:
+                    gc.callbacks.remove(cls._on_gc)
+            entry = cls._loops.get(loop)
+            if entry is None or counters not in entry[1]:
+                return
+            entry[1].remove(counters)
+            if not entry[1]:
+                del cls._loops[loop]
+                if entry[0] is not None:
+                    # The wrapper lives on the instance; removing it
+                    # uncovers the selector class's own method again.
+                    del entry[0].select
+
+    @classmethod
+    def installed(cls, loop: Any = None) -> bool:
+        """Whether the gc hook (and, given ``loop``, its selector wrapper)
+        is in place."""
+        if loop is not None:
+            entry = cls._loops.get(loop)
+            if entry is None or entry[0] is None:
+                return False
+            if "select" not in vars(entry[0]):
+                return False
+        return cls._on_gc in gc.callbacks
+
+    @staticmethod
+    def _wrap(select: Any, sinks: list) -> Any:
+        perf = time.perf_counter
+
+        def wrapped(timeout: "float | None" = None) -> Any:
+            annotation = _annotation_type()
+            ann = (annotation("mm.loop_idle") if annotation.is_enabled()
+                   else None)
+            if ann is not None:
+                ann.__enter__()
+            t = perf()
+            try:
+                return select(timeout)
+            finally:
+                waited = perf() - t
+                for counters in sinks:
+                    counters.inc("loop_idle_s", waited)
+                if ann is not None:
+                    ann.__exit__(None, None, None)
+
+        return wrapped
+
+    @classmethod
+    def _on_gc(cls, phase: str, info: dict) -> None:
+        # Collections never nest and hold the interpreter lock throughout,
+        # so one slot carries a collection from its start to its stop.
+        if phase == "start":
+            annotation = _annotation_type()
+            if annotation.is_enabled():
+                cls._gc_ann = annotation("mm.gc",
+                                         generation=info["generation"])
+                cls._gc_ann.__enter__()
+            cls._gc_t = time.perf_counter()
+            return
+        paused = time.perf_counter() - cls._gc_t
+        if cls._gc_ann is not None:
+            cls._gc_ann.__exit__(None, None, None)
+            cls._gc_ann = None
+        gen = info["generation"]
+        for counters in tuple(cls._sinks):
+            counters.inc("gc_s", paused)
+            counters.inc("gc_pauses")
+            counters.inc(f"gc_s_gen{gen}", paused)
+            counters.inc(f"gc_pauses_gen{gen}")
 
 #: Marks recorded once per engine window and merged into member traces.
 WINDOW_STAGES = ("dispatch", "h2d", "device_step", "readback_seal",

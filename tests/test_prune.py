@@ -84,9 +84,7 @@ def _assert_identical(a, b):
     compared to 1 ulp: pruning changes no math, but the dense and pruned
     programs compile the shared scoring expression at different tile shapes
     and the CPU test backend's instruction selection (FMA contraction) can
-    round intermediates differently per shape. On the TPU backend the same
-    comparison measures bit-identical (scripts/profile_stages.py --mode
-    prunecheck)."""
+    round intermediates differently per shape."""
     (pa, qa, ca, da), (pb, qb, cb, db) = a, b
     np.testing.assert_array_equal(qa, qb)
     np.testing.assert_array_equal(ca, cb)
