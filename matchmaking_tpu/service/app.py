@@ -2473,17 +2473,24 @@ class _QueueRuntime:
             for d in deliveries:
                 self._ack(d)
             if any(d.trace is not None for d in deliveries):
-                matched_ids = set(out.m_id_a.tolist()) | set(out.m_id_b.tolist())  # matchlint: ignore[perf] O(window matches) OUTCOME columns, traced windows only — not a pool scan
-                rejected_ids = {pid for pid, _ in out.rejected}
-                t_settle = time.time()
-                for d in deliveries:
-                    tr = d.trace
-                    if tr is None:
-                        continue
-                    status = ("matched" if tr.player_id in matched_ids else
-                              "rejected" if tr.player_id in rejected_ids else
-                              "queued")
-                    self._settle_trace(d, status, t_settle)
+                # The window's traces settle together, as columns: one
+                # status and "publish" mark each, then one recorder call.
+                with stage("settle", token=token):
+                    matched_ids = set(out.m_id_a.tolist()) | set(out.m_id_b.tolist())  # matchlint: ignore[perf] O(window matches) OUTCOME columns, traced windows only — not a pool scan
+                    rejected_ids = {pid for pid, _ in out.rejected}
+                    t_settle = time.time()
+                    settled = []
+                    for d in deliveries:
+                        tr = d.trace
+                        if tr is None:
+                            continue
+                        tr.status = ("matched" if tr.player_id in matched_ids
+                                     else "rejected"
+                                     if tr.player_id in rejected_ids
+                                     else "queued")
+                        tr.mark("publish", t_settle)
+                        settled.append(tr)
+                    self.app.recorder.complete_window(settled)
             m.counters.inc("windows")
             m.counters.inc("requests_batched", len(deliveries))
         self.app.attribution.observe_site(
@@ -2706,14 +2713,12 @@ class _QueueRuntime:
             bodies = [None] * (2 * n)
         m = self.app.metrics
         m.counters.inc("players_matched", 2 * n)
-        rec = m.latency["match_wait"]
-        q = self.queue_cfg.name
-        for enq in (out.m_enq_a, out.m_enq_b):
-            for w in (now - enq[enq != 0.0]).tolist():
-                rec.record(w)
-                # The same sample feeds the bucketed histogram, so its
-                # p99-from-buckets is checkable against the recorder.
-                m.observe_stage(q, "e2e", w)
+        waits = np.concatenate([now - enq[enq != 0.0]
+                                for enq in (out.m_enq_a, out.m_enq_b)])
+        m.latency["match_wait"].record_many(waits)
+        # The same samples feed the bucketed histogram, so its
+        # p99-from-buckets is checkable against the recorder.
+        m.observe_stage_many(self.queue_cfg.name, "e2e", waits)
         reply_a, reply_b = out.m_reply_a.tolist(), out.m_reply_b.tolist()
         corr_a, corr_b = out.m_corr_a.tolist(), out.m_corr_b.tolist()
         wa_l, wb_l = wa_ms.tolist(), wb_ms.tolist()

@@ -61,6 +61,9 @@ served outcome (not shed/expired/rejected/timeout) within the target.
 Shed/expired requests burn the SLO on purpose — an objective met by
 rejecting everyone is not met.
 
+``observe_window`` takes many traces of one mark sequence at once, as
+columns, and adds what ``observe`` would for each.
+
 Loop-confined like the batcher and AdmissionController: ``observe`` runs on
 the event loop (every trace-settle path does), never from worker threads —
 there is deliberately no lock here.
@@ -68,7 +71,10 @@ there is deliberately no lock here.
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any
+
+import numpy as np
 
 from matchmaking_tpu.utils.metrics import DEFAULT_STAGE_BUCKETS, Histogram
 
@@ -175,6 +181,36 @@ def decompose(trace) -> dict[str, Any]:
     }
 
 
+#: Mark sequences whose column plans are kept; a window has one or a few.
+_PLANS_MAX = 256
+
+
+class _Plan:
+    """How ``Attribution.observe_window`` reads the gap columns of one mark
+    sequence: each category with its kind and its columns, in the order
+    the gaps first reach it, and the columns of work and of wait."""
+
+    __slots__ = ("categories", "work", "wait")
+
+    def __init__(self, names: tuple[str, ...]):
+        cols: dict[str, tuple[str, list[int]]] = {}
+        work: list[int] = []
+        wait: list[int] = []
+        for j in range(len(names) - 1):
+            category, kind = classify(names[j], names[j + 1])
+            cols.setdefault(category, (kind, []))[1].append(j)
+            (work if kind == WORK else wait).append(j)
+        self.categories = [(category, kind, np.asarray(c, dtype=np.intp))
+                           for category, (kind, c) in cols.items()]
+        self.work = np.asarray(work, dtype=np.intp)
+        self.wait = np.asarray(wait, dtype=np.intp)
+
+
+def _count_into(counts: dict[str, int], statuses: list[str]) -> None:
+    for s, c in Counter(statuses).items():
+        counts[s] = counts.get(s, 0) + c
+
+
 class _Category:
     __slots__ = ("kind", "gaps", "traces", "total_s", "hist")
 
@@ -265,6 +301,8 @@ class Attribution:
         #: span/status/SLO splits; 1 keeps the pre-tier shape (and cost).
         self.tiers = max(1, tiers)
         self._queues: dict[str, _QueueAttribution] = {}
+        #: mark names → how ``observe_window`` reads their gap columns
+        self._plans: dict[tuple[str, ...], _Plan] = {}
 
     def _queue(self, q: str) -> _QueueAttribution:
         qa = self._queues.get(q)
@@ -323,6 +361,65 @@ class Attribution:
                 ts.slo_total += 1
                 if good:
                     ts.slo_good += 1
+
+    def observe_window(self, queue: str, names: tuple[str, ...],
+                       gaps: np.ndarray, totals: np.ndarray,
+                       statuses: list[str], tiers: np.ndarray) -> None:
+        """``observe`` for many traces of one queue that share the mark
+        names ``names``, as columns: ``gaps`` is (traces × len(names)-1)
+        of clipped mark differences, ``totals`` each trace's span,
+        ``statuses`` and ``tiers`` each trace's. Adds exactly what a loop
+        of ``observe`` would (sums up to rounding)."""
+        n = len(totals)
+        if not n:
+            return
+        plan = self._plans.get(names)
+        if plan is None:
+            if len(self._plans) >= _PLANS_MAX:
+                self._plans.clear()
+            plan = self._plans[names] = _Plan(names)
+        qa = self._queue(queue)
+        for category, kind, cols in plan.categories:
+            cat = qa.categories.get(category)
+            if cat is None:
+                cat = qa.categories[category] = _Category(kind, self.buckets)
+            block = gaps[:, cols]
+            cat.gaps += n * len(cols)
+            # A category counts each trace that touches it once.
+            cat.traces += n
+            cat.total_s += float(block.sum())
+            cat.hist.observe_many(block.ravel())
+        span_work = gaps[:, plan.work].sum(axis=1)
+        span_wait = gaps[:, plan.wait].sum(axis=1)
+        qa.work_s += float(span_work.sum())
+        qa.wait_s += float(span_wait.sum())
+        qa.spans += n
+        qa.total_hist.observe_many(totals)
+        _count_into(qa.statuses, statuses)
+        good = None
+        if self.slo_target_s > 0:
+            served = np.fromiter((s in _SERVED_STATUSES for s in statuses),
+                                 bool, n)
+            good = served & (totals <= self.slo_target_s)
+            qa.slo_total += n
+            qa.slo_good += int(good.sum())
+        if self.tiers > 1:
+            tier_of = np.clip(tiers, 0, self.tiers - 1)
+            for tier in np.unique(tier_of).tolist():
+                sel = tier_of == tier
+                ts = qa.tiers.get(tier)
+                if ts is None:
+                    ts = qa.tiers[tier] = _TierStats(self.buckets)
+                ts.spans += int(sel.sum())
+                ts.work_s += float(span_work[sel].sum())
+                ts.wait_s += float(span_wait[sel].sum())
+                _count_into(ts.statuses,
+                            [s for s, keep in zip(statuses, sel.tolist())
+                             if keep])
+                ts.total_hist.observe_many(totals[sel])
+                if good is not None:
+                    ts.slo_total += int(sel.sum())
+                    ts.slo_good += int(good[sel].sum())
 
     def observe_site(self, queue: str, category: str, seconds: float,
                      rows: int, kind: str = WORK) -> None:

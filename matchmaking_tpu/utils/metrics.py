@@ -3,7 +3,8 @@
 The reference leans on Elixir ``Logger`` and BEAM introspection; the rebuild
 makes the BASELINE headline numbers (matches/sec, p50/p99 end-to-end latency,
 pool occupancy, batch fill, recompile count) first-class (SURVEY.md §5
-"Metrics/logging/observability"). Pure stdlib, no deps.
+"Metrics/logging/observability"). Stdlib plus numpy, for the batched
+observations a window settles at once.
 """
 
 from __future__ import annotations
@@ -15,6 +16,8 @@ import threading
 import time
 from collections import defaultdict, deque
 from dataclasses import dataclass, field
+
+import numpy as np
 
 
 class Counter:
@@ -47,6 +50,16 @@ class LatencyRecorder:
         self._count += 1
         if seconds > self._max:
             self._max = seconds
+
+    def record_many(self, seconds: np.ndarray) -> None:
+        """What a loop of ``record`` over ``seconds`` would do, in order."""
+        if not len(seconds):
+            return
+        self._samples.extend(seconds.tolist())
+        self._count += len(seconds)
+        top = float(seconds.max())
+        if top > self._max:
+            self._max = top
 
     def __len__(self) -> int:
         return self._count
@@ -97,7 +110,7 @@ class Histogram:
     show the bimodal batcher-wait or H2D-stall signatures that explain a
     p99 outlier."""
 
-    __slots__ = ("buckets", "counts", "overflow", "count", "sum")
+    __slots__ = ("buckets", "counts", "overflow", "count", "sum", "_edges")
 
     def __init__(self, buckets: tuple[float, ...] = DEFAULT_STAGE_BUCKETS):
         # Sorted is a bisect precondition AND a prom-exposition requirement
@@ -108,6 +121,7 @@ class Histogram:
         self.overflow = 0  # observations above the last bucket (+Inf)
         self.count = 0
         self.sum = 0.0
+        self._edges: np.ndarray | None = None
 
     def observe(self, seconds: float) -> None:
         self.count += 1
@@ -117,6 +131,26 @@ class Histogram:
             self.counts[i] += 1
         else:
             self.overflow += 1
+
+    def observe_many(self, seconds: np.ndarray) -> None:
+        """What a loop of ``observe`` over ``seconds`` would add: the same
+        bucket for each value (``searchsorted(side="left")`` is
+        ``bisect_left``), ``count`` by its length and ``sum`` by its sum."""
+        n = len(seconds)
+        if not n:
+            return
+        if self._edges is None:
+            self._edges = np.asarray(self.buckets, dtype=np.float64)
+        nb = len(self.buckets)
+        per = np.bincount(np.searchsorted(self._edges, seconds, side="left"),
+                          minlength=nb + 1).tolist()
+        counts = self.counts
+        for i in range(nb):
+            if per[i]:
+                counts[i] += per[i]
+        self.overflow += per[nb]
+        self.count += n
+        self.sum += float(seconds.sum())
 
     def percentile(self, p: float) -> float:
         """Upper edge of the bucket holding the p-th percentile (NaN when
@@ -231,14 +265,22 @@ class Metrics:
     def record_latency(self, name: str, seconds: float) -> None:
         self.latency[name].record(seconds)
 
-    def observe_stage(self, queue: str, stage: str, seconds: float) -> None:
+    def _stage_histogram(self, queue: str, stage: str) -> Histogram:
         per_q = self.stages.get(queue)
         if per_q is None:
             per_q = self.stages[queue] = {}
         hist = per_q.get(stage)
         if hist is None:
             hist = per_q[stage] = Histogram(self.stage_buckets)
-        hist.observe(seconds)
+        return hist
+
+    def observe_stage(self, queue: str, stage: str, seconds: float) -> None:
+        self._stage_histogram(queue, stage).observe(seconds)
+
+    def observe_stage_many(self, queue: str, stage: str,
+                           seconds: np.ndarray) -> None:
+        """``observe_stage`` for a column of samples at once."""
+        self._stage_histogram(queue, stage).observe_many(seconds)
 
     def set_gauge(self, name: str, value: float) -> None:
         self.gauges[name] = float(value)

@@ -1,7 +1,7 @@
 """Request-lifecycle flight recorder (SURVEY.md §5 per-stage tracing).
 
 The BASELINE north star asserts a p99; this module is what *explains* one.
-Three pieces, all bounded-memory and stdlib-only:
+Three pieces, all bounded-memory:
 
 - ``TraceContext`` — a lightweight per-delivery trace: an id plus an
   append-only list of ``(stage, wall_clock)`` marks. Stamped at broker
@@ -16,7 +16,8 @@ Three pieces, all bounded-memory and stdlib-only:
   exceeds the configured threshold keeps its full stage breakdown. On
   completion, every adjacent mark pair feeds the shared per-stage latency
   histograms (utils/metrics.py) — the true-histogram replacement for the
-  averages-only ``span_report``.
+  averages-only ``span_report``. A window's traces settle together, as
+  columns (``complete_window``).
 - ``EventLog`` — one bounded ring of lifecycle events (breaker trips,
   probes, delegations, re-promotions, revives, chaos faults, partitions,
   dead-letters) that were previously only visible as scattered counters.
@@ -57,6 +58,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Iterable
+
+import numpy as np
 
 log = logging.getLogger(__name__)
 
@@ -383,9 +386,20 @@ class EventLog:
         return rows[-limit:] if limit else rows
 
 
+#: Below this many traces of one mark signature, ``complete_window``
+#: settles them one at a time: numpy's fixed cost per call outweighs what a
+#: column saves on a handful of rows.
+_COLUMN_MIN_TRACES = 8
+
+
 class FlightRecorder:
     """Per-queue rings of completed traces + slow exemplars; feeds the
-    per-stage histograms on every completion."""
+    per-stage histograms on every completion.
+
+    ``complete`` settles one trace; ``complete_window`` settles a window's
+    traces together, as columns, and records exactly what a loop of
+    ``complete`` would. The counters ``settled_single`` and
+    ``settled_columnar`` count the traces settled each way."""
 
     def __init__(self, metrics, ring: int = 256, slow_ring: int = 64,
                  slow_threshold_s: float = 0.25):
@@ -406,6 +420,59 @@ class FlightRecorder:
         pairs into the shared histograms, record it in the recent ring, and
         keep it as a slow exemplar when the enqueue→publish span exceeds
         the threshold."""
+        self._observe(trace)
+        if self._metrics is not None:
+            self._metrics.counters.inc("settled_single")
+        self._keep(trace.queue, [trace])
+
+    def complete_window(self, traces: list[TraceContext]) -> None:
+        """Settle a window's traces at once: what ``complete`` does for
+        each, in the same order. Traces are grouped by queue and mark
+        names; each group of at least ``_COLUMN_MIN_TRACES`` becomes one
+        (traces × marks) array whose clipped differences feed each stage
+        histogram and the attribution a column at a time. Smaller groups
+        settle one trace at a time."""
+        groups: dict[tuple, list] = {}
+        for tr in traces:
+            names, times = zip(*tr.marks)
+            key = (tr.queue, names)
+            group = groups.get(key)
+            if group is None:
+                groups[key] = group = ([], [])
+            group[0].append(tr)
+            group[1].append(times)
+        counters = (self._metrics.counters if self._metrics is not None
+                    else None)
+        for (q, names), (members, times) in groups.items():
+            n = len(members)
+            if n < _COLUMN_MIN_TRACES or len(names) < 2:
+                for tr in members:
+                    self._observe(tr)
+                if counters is not None:
+                    counters.inc("settled_single", n)
+                continue
+            marks = np.fromiter(itertools.chain.from_iterable(times),
+                                np.float64, n * len(names)).reshape(n, -1)
+            gaps = np.maximum(np.diff(marks, axis=1), 0.0)
+            totals = marks[:, -1] - marks[:, 0]
+            if self._metrics is not None:
+                observe = self._metrics.observe_stage_many
+                for j, name in enumerate(names[1:]):
+                    observe(q, name, gaps[:, j])
+                observe(q, "total", np.maximum(totals, 0.0))
+                counters.inc("settled_columnar", n)
+            if self.attribution is not None:
+                self.attribution.observe_window(
+                    q, names, gaps, totals,
+                    [tr.status or "unknown" for tr in members],
+                    np.fromiter((tr.tier for tr in members), np.int64, n))
+        queues = {q for q, _names in groups}
+        for q in queues:
+            self._keep(q, traces if len(queues) == 1
+                       else [tr for tr in traces if tr.queue == q])
+
+    def _observe(self, trace: TraceContext) -> None:
+        """One trace's stage histograms and attribution."""
         q = trace.queue
         marks = trace.marks
         if self._metrics is not None:
@@ -417,15 +484,21 @@ class FlightRecorder:
             observe(q, "total", max(0.0, marks[-1][1] - marks[0][1]))
         if self.attribution is not None:
             self.attribution.observe(trace)
-        ring = self._recent.get(q)
+
+    def _keep(self, queue: str, traces: "list[TraceContext]") -> None:
+        """Record settled traces of ``queue`` in its recent ring, in order,
+        and in its slow ring those whose span reaches the threshold."""
+        ring = self._recent.get(queue)
         if ring is None:
-            ring = self._recent[q] = deque(maxlen=self._ring)
-        ring.append(trace)
-        if trace.total_s >= self.slow_threshold_s:
-            slow = self._slow.get(q)
+            ring = self._recent[queue] = deque(maxlen=self._ring)
+        ring.extend(traces)
+        threshold = self.slow_threshold_s
+        slow_ones = [tr for tr in traces if tr.total_s >= threshold]
+        if slow_ones:
+            slow = self._slow.get(queue)
             if slow is None:
-                slow = self._slow[q] = deque(maxlen=self._slow_ring)
-            slow.append(trace)
+                slow = self._slow[queue] = deque(maxlen=self._slow_ring)
+            slow.extend(slow_ones)
 
     def percentile_exemplar(self, queue: str,
                             p: float = 99.0) -> TraceContext | None:

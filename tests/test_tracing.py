@@ -131,7 +131,8 @@ def test_served_path_spans_nest_share_a_token_and_hold_the_marks(tmp_path):
     for span in ("mm.ingest", "mm.flush", "mm.dispatch", "mm.dedupe",
                  "mm.alloc", "mm.pack", "mm.h2d", "mm.launch",
                  "mm.quality_accum", "mm.collect", "mm.finalize",
-                 "mm.egress", "mm.encode", "mm.publish", "mm.loop_idle"):
+                 "mm.egress", "mm.encode", "mm.publish", "mm.settle",
+                 "mm.loop_idle"):
         assert span in names, (span, sorted(n for n in names
                                             if n.startswith("mm.")))
 
@@ -153,7 +154,7 @@ def test_served_path_spans_nest_share_a_token_and_hold_the_marks(tmp_path):
             assert parent(ev, "mm.dispatch")[3]["token"] == ev[3]["token"]
     for ev in by_name["mm.finalize"]:
         parent(ev, "mm.collect")
-    for child in ("mm.encode", "mm.publish"):
+    for child in ("mm.encode", "mm.publish", "mm.settle"):
         for ev in by_name[child]:
             assert parent(ev, "mm.egress")[3]["token"] == ev[3]["token"]
 
