@@ -2,11 +2,14 @@
 
 ``BENCHMARK.json`` at the repository root names the cells. A cell is one
 configuration (``benchmark/configs/<name>.json``: the service settings,
-the chips, the waiting pool) under one traffic mix
-(``benchmark/traffic/<name>.json``: the arrival and rating model). Each
-per-layer metric has a reader of its own (``benchmark/metrics/<name>.py``).
-A cell, configuration or metric is added by adding files and entries; no
-code here names one.
+the chips, the waiting pool, and optionally its queue's ``kind``) under
+one traffic mix (``benchmark/traffic/<name>.json``: the arrival and rating
+model). A queue kind (``benchmark/kinds/<name>.py``) gives the requests'
+extra body fields and the plain reference for that kind of queue; a
+configuration without one is a 1v1 queue (``benchmark/reference.py``).
+Each metric has a reader of its own (``benchmark/metrics/<name>.py``). A
+cell, configuration, kind or metric is added by adding files and entries;
+no code here names one.
 
 Run one cell from the repository root:
 
@@ -14,6 +17,6 @@ Run one cell from the repository root:
         --seconds 10 --trace 0
 
 The yardstick lives here and nowhere in the program: the traffic
-generator, the plain reference that decides ``correct``, the table of
+generator, the plain references that decide ``correct``, the table of
 peaks, the byte count of a formation window and the trace reduction.
 """

@@ -1,16 +1,25 @@
-"""Find a cell, its configuration and its traffic mix by name.
+"""Find a cell, its configuration, its traffic mix and its queue kind by
+name.
 
-Everything a cell needs is data: ``BENCHMARK.json`` names the cell's
-configuration and traffic, and each lives in a file of its own under
-``benchmark/configs/`` and ``benchmark/traffic/``. Adding a cell is adding
-those files and an entry; nothing here changes.
+``BENCHMARK.json`` names the cell's configuration and traffic, and each
+lives in a file of its own under ``benchmark/configs/`` and
+``benchmark/traffic/``. A configuration may name its queue's kind
+(``"kind": "<name>"``): a module of its own, ``benchmark/kinds/<name>.py``,
+that gives the requests' extra body fields and the plain reference that
+decides ``correct`` (see ``load_kind``). A configuration without a kind is
+a 1v1 rating-distance queue, judged by ``benchmark/reference.py``. Adding
+a cell is adding those files and an entry; nothing here changes.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
+import re
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Any
 
 #: The benchmark's own directory and the checkout it sits in.
@@ -32,11 +41,49 @@ class Cell:
     end_to_end: tuple[str, ...]
     #: Per-layer metric names this cell reports in a traced run.
     per_layer: tuple[str, ...]
+    #: The checkout the cell was found in, where its kind's file lies.
+    root: str = ROOT
+
+    @property
+    def kind(self) -> ModuleType:
+        """The module of the configuration's queue kind."""
+        return load_kind(self.config.get("kind"), self.root)
 
 
 def load_json(path: str) -> Any:
     with open(path) as f:
         return json.load(f)
+
+
+#: What every kind module defines. ``fields(rng, n, config)``: the extra
+#: body fields of ``n`` requests, ``{body key: column}``, drawn from
+#: ``rng`` (``{}`` for none). ``LIMITS``: the numbers compared and their
+#: limits. ``compare(data, control=False)``: the numbers of one run's
+#: ``reference.RunData``. ``failures(numbers)``, ``report(numbers)``: as
+#: in ``benchmark/reference.py``.
+KIND_API = ("fields", "LIMITS", "compare", "failures", "report")
+_KIND_NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+@functools.cache
+def load_kind(name: str | None, root: str = ROOT) -> ModuleType:
+    """The queue kind ``name``: the module ``benchmark/kinds/<name>.py``
+    under ``root``, loaded once; ``benchmark.reference`` (1v1) for None."""
+    if name is None:
+        from benchmark import reference
+
+        return reference
+    if not _KIND_NAME.match(name):
+        raise ValueError(f"bad kind name {name!r}")
+    path = os.path.join(root, os.path.basename(HERE), "kinds", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_kind_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [a for a in KIND_API if not hasattr(mod, a)]
+    if missing:
+        raise AttributeError(f"kind {name!r} ({path}) lacks {missing}")
+    return mod
 
 
 def benchmark_spec(root: str = ROOT) -> dict[str, Any]:
@@ -71,6 +118,7 @@ def find_cell(name: str, root: str = ROOT,
                          if _applies(m, name)),
         per_layer=tuple(m["name"] for m in spec["per_layer"]
                         if _applies(m, name)),
+        root=root,
     )
 
 

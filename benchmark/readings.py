@@ -1,15 +1,15 @@
 """Read the numbers that ``correct`` compares, over many seeds, beside the
-control's: the readings the limits in ``benchmark/reference.py`` are set
-from.
+control's: the readings the limits of the cell's queue kind (for 1v1,
+``benchmark/reference.py``) are set from.
 
     python3 -m benchmark.readings --workload elo_1v1.search \\
         --seeds 101,102,103 --seconds 10 [--out rows.jsonl]
 
 Runs the cell's window at its own load and size once per seed, in one
 process (so set-up is paid once), and prints one JSON row per seed: the
-program's numbers and ``control_skipped``, the same reading of the
-bfloat16 reference's picks for the same searchers. The benchmark's own
-runs never run the control.
+program's numbers and the control's (for 1v1 ``control_skipped``, the
+same reading of the bfloat16 reference's picks for the same searchers).
+The benchmark's own runs never run the control.
 """
 
 from __future__ import annotations
@@ -19,7 +19,7 @@ import json
 import sys
 import time
 
-from benchmark import cells, reference
+from benchmark import cells
 from benchmark.run import chip_jax, run_cell
 
 
@@ -38,10 +38,10 @@ def main(argv: list[str] | None = None) -> int:
         run, _ = run_cell(cell, seed, args.seconds, False,
                           t_process=time.perf_counter())
         t = time.perf_counter()
-        numbers = reference.compare(run.data, control=True)
+        numbers = cell.kind.compare(run.data, control=True)
         row = {"workload": cell.name, "seed": seed,
                "reference_s": time.perf_counter() - t,
-               "correct": not reference.failures(numbers), **numbers,
+               "correct": not cell.kind.failures(numbers), **numbers,
                "log": run.log}
         print(json.dumps(row), flush=True)
         if args.out:

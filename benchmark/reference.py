@@ -1,4 +1,12 @@
-"""The plain reference that decides ``correct``.
+"""The plain reference that decides ``correct`` for a 1v1 queue.
+
+This is the default queue kind: a configuration that names no ``kind``
+is judged here, and its requests carry no body fields beyond id and
+rating. A configuration of another kind names a module of its own under
+``benchmark/kinds/`` that defines the same names (``cells.KIND_API``) and
+reads the same ``RunData``; its plain data for other kinds (the queue's
+team size and role slots, each request's extra fields, each match's
+teams) is ignored here.
 
 It knows the semantics of a 1v1 rating-distance queue and nothing of the
 program: the queue runs on the device engine throughout (a service that
@@ -35,7 +43,7 @@ from __future__ import annotations
 
 import bisect
 import collections
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -69,6 +77,8 @@ class Reply:
     t: float                   # receive time, seconds (client clock)
     match_id: str = ""
     players: tuple[str, ...] = ()
+    #: The match's split into teams, as its body gives it.
+    teams: tuple[tuple[str, ...], ...] = ()
 
 
 @dataclass
@@ -89,8 +99,21 @@ class RunData:
     pool_size_end: int
     device_pool_size_end: int
     #: Times the queue left the device path for the host oracle (the
-    #: service's breaker counters, and its engine and pool at the end).
+    #: service's breaker counters, the team queue's delegations, and its
+    #: engine and pool at the end).
     off_device: int = 0
+    #: The queue's settings, from the configuration's first queue.
+    team_size: int = 1
+    role_slots: tuple[str, ...] = ()
+    #: The kind's extra body fields of each restored player and each sent
+    #: request (in the order above), one column per body key.
+    pool_fields: dict[str, np.ndarray] = field(default_factory=dict)
+    sent_fields: dict[str, np.ndarray] = field(default_factory=dict)
+
+
+def fields(rng: np.random.Generator, n: int, config: dict) -> dict:
+    """A 1v1 request carries no body fields beyond its id and rating."""
+    return {}
 
 
 def f32_distance(a, b):

@@ -5,7 +5,7 @@
 
 Each run is one process: it loads, restores the cell's waiting pool, warms
 up, measures for ``--seconds``, drains, checks every answer against the
-plain reference, and prints one JSON object as the last line of standard
+plain reference of the configuration's queue kind, and prints one JSON object as the last line of standard
 output. Progress goes to standard error; its last lines are the numbers
 compared, each beside its limit. Without a TPU, or with fewer chips than
 the cell asks for, it exits non-zero and prints no result.
@@ -30,7 +30,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
-from benchmark import cells, reference  # noqa: E402
+from benchmark import cells  # noqa: E402
 
 #: JAX's persistent compilation cache: one fixed directory inside the
 #: checkout, so only a cell's first run there compiles.
@@ -63,7 +63,7 @@ def result_line(cell, run, numbers: dict, traced: bool, device: dict,
         if value is not None:
             metrics[name] = {"value": value, "unit": units[name]}
     out = {
-        "correct": not reference.failures(numbers),
+        "correct": not cell.kind.failures(numbers),
         "attempted": run.attempted,
         "failed": run.failed,
         "metrics": metrics,
@@ -75,14 +75,14 @@ def result_line(cell, run, numbers: dict, traced: bool, device: dict,
         out["device"]["window_s"] = t["window_s"]
         out["breakdown"] = {"device_ops": t["device_ops"],
                             "idle_gaps": t["idle_gaps"]}
-    out["checks"] = reference.report(numbers)
+    out["checks"] = cell.kind.report(numbers)
     return out
 
 
 def run_cell(cell, seed: int, seconds: float, traced: bool,
              t_process: float = T_PROCESS):
-    """One run of ``cell`` and the reference's numbers for it (no device
-    check here: the caller decides where it may run)."""
+    """One run of ``cell`` and its kind's reference numbers for it (no
+    device check here: the caller decides where it may run)."""
     from benchmark.serve import drive
 
     run = asyncio.run(drive(cell, seed, seconds, t_process=t_process,
@@ -92,7 +92,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool,
     if traced:
         for line in run.readings.get("trace_planes", []):
             say(f"trace plane {line}")
-    numbers = reference.compare(run.data)
+    numbers = cell.kind.compare(run.data)
     return run, numbers
 
 

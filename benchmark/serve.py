@@ -80,16 +80,37 @@ def _build_config(cell) -> Any:
     return Config.from_dict(cell.config["service"])
 
 
-def _pool_columns(pool: traffic.Arrivals, now: float):
-    from matchmaking_tpu.service.contract import DEFAULT_RD, RequestColumns
+#: The request body fields a restored pool holds as columns.
+POOL_COLUMN_FIELDS = ("region", "game_mode", "rating_deviation")
+
+
+def _pool_columns(pool: traffic.Arrivals, now: float, engine):
+    """The waiting pool as the engine restores it. The kind's region and
+    game mode enter through the engine's own interners (absent, each is
+    the wildcard, code 0); its rating deviation as given."""
+    from matchmaking_tpu.service.contract import (
+        ANY,
+        DEFAULT_RD,
+        RequestColumns,
+    )
 
     n = len(pool)
+    f = pool.fields
+    other = sorted(set(f) - set(POOL_COLUMN_FIELDS))
+    if other:
+        raise ValueError(f"a restored pool has no column for {other}")
+    region, mode = np.zeros(n, np.int32), np.zeros(n, np.int32)
+    if "region" in f or "game_mode" in f:
+        wild = np.full(n, ANY, object)
+        region, mode = engine.intern_columns(
+            f.get("region", wild).tolist(), f.get("game_mode", wild).tolist())
     return RequestColumns(
         ids=np.asarray(pool.ids, dtype=object),
         rating=pool.rating.astype(np.float32),
-        rd=np.full(n, DEFAULT_RD, np.float32),
-        region=np.zeros(n, np.int32),
-        mode=np.zeros(n, np.int32),
+        rd=np.asarray(f.get("rating_deviation", np.full(n, DEFAULT_RD)),
+                      np.float32),
+        region=region,
+        mode=mode,
         threshold=np.full(n, np.nan, np.float32),
         enqueued_at=np.full(n, now, np.float64),
     )
@@ -138,35 +159,30 @@ class _Client:
                 out.append(Reply(
                     to=pid, status=body.get("status", ""), t=t,
                     match_id=m.get("match_id", ""),
-                    players=tuple(m.get("players", ()))))
+                    players=tuple(m.get("players", ())),
+                    teams=tuple(tuple(x) for x in m.get("teams", ()))))
         return out
 
     def close(self) -> None:
         self.app.broker.basic_cancel(self.tag)
 
 
-class _GcClock:
-    """Pauses of the garbage collector, by generation (a log line, not a
-    metric: the host's stalls show in the tail, and this says whether the
-    collector is one of them)."""
+#: The program's counters of the garbage collector's pauses.
+GC_COUNTERS = ("gc_s", "gc_pauses", "gc_pauses_gen2")
 
-    def __init__(self):
-        self.pauses: list[tuple[int, float]] = []
-        self._t = 0.0
 
-    def __call__(self, phase: str, info: dict) -> None:
-        if phase == "start":
-            self._t = time.perf_counter()
-        else:
-            self.pauses.append((info["generation"],
-                                time.perf_counter() - self._t))
+def _gc_counters(app) -> dict[str, float]:
+    counters = app.metrics.counters.snapshot()
+    return {k: counters.get(k, 0.0) for k in GC_COUNTERS}
 
-    def summary(self) -> dict[str, float]:
-        g2 = [s for g, s in self.pauses if g == 2]
-        return {"gc_pauses": len(self.pauses),
-                "gc_ms_total": 1e3 * sum(s for _, s in self.pauses),
-                "gc_gen2_pauses": len(g2),
-                "gc_gen2_ms_max": 1e3 * max(g2, default=0.0)}
+
+def _gc_log(a: dict, b: dict) -> dict[str, float]:
+    """The collector's pauses between two readings (log lines, not
+    metrics: the host's stalls show in the tail, and these say whether
+    the collector is one of them)."""
+    return {"gc_pauses": int(b["gc_pauses"] - a["gc_pauses"]),
+            "gc_ms_total": 1e3 * (b["gc_s"] - a["gc_s"]),
+            "gc_gen2_pauses": int(b["gc_pauses_gen2"] - a["gc_pauses_gen2"])}
 
 
 def _quiet(app, rt, queue: str) -> bool:
@@ -236,10 +252,12 @@ ORACLE_COUNTERS = ("engine_crashes", "breaker_trips",
 
 
 def _off_device(app, rt, platform: str) -> int:
-    """How often the queue left the device path: the oracle counters, plus
-    one if its engine is no longer the device engine or its pool no longer
-    lives on ``platform``. A fault the service survives by falling back to
-    the host oracle answers correctly, and must still fail the run."""
+    """How often the queue left the device path: the oracle counters and
+    the team queue's delegations to the host oracle (the engine was built
+    by this run), plus one if its engine is no longer the device engine or
+    its pool no longer lives on ``platform`` (a pool still delegated holds
+    nothing there). A fault the service survives by falling back to the
+    host oracle answers correctly, and must still fail the run."""
     import jax
 
     from matchmaking_tpu.engine.tpu import TpuEngine
@@ -248,6 +266,7 @@ def _off_device(app, rt, platform: str) -> int:
     engine = rt.engine
     if not isinstance(engine, TpuEngine):
         return n + 1
+    n += int(engine.counters.get("team_delegated", 0))
     where = {d.platform for leaf in jax.tree.leaves(engine._dev_pool)
              for d in leaf.devices()}
     return n + (where != {platform})
@@ -271,6 +290,7 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
 
     cfg = _build_config(cell)
     qcfg = cfg.queues[0]
+    kind = cell.kind
     queue = qcfg.name
     devices = jax.devices()[:cell.chips]
     app = MatchmakingApp(cfg)
@@ -278,25 +298,29 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
     client = None
     try:
         rt = app.runtime(queue)
-        pool = traffic.waiting_pool(cell.config, seed)
+        pool, = traffic.add_fields(
+            [traffic.waiting_pool(cell.config, seed)], kind, cell.config,
+            seed, traffic.STREAM_POOL_FIELDS)
         t = time.perf_counter()
         async with rt._engine_lock:
-            rt.engine.restore_columns(_pool_columns(pool, time.time()),
-                                      time.time())
+            rt.engine.restore_columns(
+                _pool_columns(pool, time.time(), rt.engine), time.time())
             jax.block_until_ready(rt.engine._dev_pool)
         say(f"restored pool={rt.engine.pool_size()} "
             f"in {time.perf_counter() - t:.3f}s")
 
         client = _Client(app, queue)
+        sent: list[traffic.Arrivals] = []
         sent_ids: list[str] = []
-        sent_rating: list[np.ndarray] = []
         sent_t: list[np.ndarray] = []
-        for burst in traffic.warmup_bursts(cell.config, cell.traffic, seed):
+        for burst in traffic.add_fields(
+                traffic.warmup_bursts(cell.config, cell.traffic, seed), kind,
+                cell.config, seed, traffic.STREAM_WARMUP_FIELDS):
             t = time.perf_counter()
             for pid, body in zip(burst.ids, burst.bodies()):
                 client.publish(pid, body)
+            sent.append(burst)
             sent_ids += burst.ids
-            sent_rating.append(burst.rating)
             sent_t.append(np.full(len(burst), t))
             # An unanswered warm-up request is not waited for beyond the
             # limit: the reference counts it with the window's.
@@ -305,7 +329,9 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
             say(f"warm-up burst={len(burst)} answered={done} "
                 f"took {time.perf_counter() - t:.3f}s")
 
-        arr = traffic.window_arrivals(cell.traffic, seed, seconds)
+        arr, = traffic.add_fields(
+            [traffic.window_arrivals(cell.traffic, seed, seconds)], kind,
+            cell.config, seed, traffic.STREAM_WINDOW_FIELDS)
         bodies = arr.bodies()
         ids = arr.ids
         due = arr.due
@@ -320,8 +346,7 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
         # garbage collected, so full collections in the window fall where
         # the window's own work puts them, the same from run to run.
         gc.collect()
-        gc_clock = _GcClock()
-        gc.callbacks.append(gc_clock)
+        gc0 = _gc_counters(app)
         t0 = time.perf_counter()
         setup_s = t0 - t_process
         i = 0
@@ -344,7 +369,7 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
                 wake.append(trace_at)
             await asyncio.sleep(max(0.0, min(wake) - now))
         t_close = time.perf_counter()
-        gc.callbacks.remove(gc_clock)
+        gc1 = _gc_counters(app)
         s1 = _snapshot(app, rt, queue)
         trace_s = None
         if traced:
@@ -352,16 +377,19 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
             trace_s = t_close - marks["trace_start"]["t"]
         got_in_window = [b for t, bs, _ in client.bursts if t <= t_close
                          for b in bs]
+        sent.append(arr)
         sent_ids += arr.ids
-        sent_rating.append(arr.rating)
         sent_t.append(t0 + pub)
 
         drained = await _settle(app, rt, client, queue, sent_ids, DRAIN_S)
         t_drained = time.perf_counter() - t_close
         async with rt._engine_lock:
             pool_end = rt.engine.pool_size()
-            device_pool_end = int(np.asarray(
-                jax.device_get(rt.engine._dev_pool["active"])).sum())
+            # A pool that left the device (a delegated team queue) holds
+            # no one there: the reference's pool gap then fails the run.
+            dev_pool = getattr(rt.engine, "_dev_pool", None)
+            device_pool_end = 0 if dev_pool is None else int(np.asarray(
+                jax.device_get(dev_pool["active"])).sum())
         off_device = _off_device(app, rt, devices[0].platform)
         memory = _memory_peak(devices)
         lag = pub - due
@@ -370,10 +398,11 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
             "pool_end": pool_end, "drained": drained,
             "drain_s": round(t_drained, 3),
             "compiles_in_window": s1["compiles"] - s0["compiles"],
-            "windows": s1["spans"]["windows"] - s0["spans"]["windows"],
+            "windows": (s1["spans"].get("windows", 0)
+                        - s0["spans"].get("windows", 0)),
             "generator_lag_ms_mean": float(lag.mean() * 1e3) if n else 0.0,
             "generator_lag_ms_max": float(lag.max() * 1e3) if n else 0.0,
-            **gc_clock.summary(),
+            **_gc_log(gc0, gc1),
         }
         readings = {
             "chips": cell.chips,
@@ -401,10 +430,14 @@ async def drive(cell, seed: int, seconds: float, *, t_process: float,
     data = RunData(
         threshold=float(qcfg.rating_threshold),
         pool_ids=pool.ids, pool_rating=pool.rating,
-        sent_ids=sent_ids, sent_rating=np.concatenate(sent_rating),
+        sent_ids=sent_ids,
+        sent_rating=np.concatenate([a.rating for a in sent]),
         sent_t=np.concatenate(sent_t), replies=replies,
         pool_size_end=pool_end, device_pool_size_end=device_pool_end,
-        off_device=off_device)
+        off_device=off_device, team_size=int(qcfg.team_size),
+        role_slots=tuple(qcfg.role_slots), pool_fields=pool.fields,
+        sent_fields={k: np.concatenate([a.fields[k] for a in sent])
+                     for k in arr.fields})
 
     # Latency of each window request to its matched reply, from its due
     # time; distinct matches answered inside the window.

@@ -20,16 +20,25 @@ offers the same amount of work in another order.
 A request's rating is a whole number of hundredths, as its body carries
 it: ``k / 100`` for an integer ``k``, which formats to the body's decimal
 and parses back to the same double.
+
+A configuration's queue kind (``cells.load_kind``) may give each request
+more body fields (region, game mode, rating deviation, ...). They are
+drawn by ``add_fields`` from streams of their own, so the ratings and
+times above are drawn exactly as without them, and a request without them
+has the body ``{"id":…,"rating":…}`` byte for byte.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import json
+from dataclasses import dataclass, field, replace
 
 import numpy as np
 
 #: Independent random streams, one per use of the seed.
 STREAM_POOL, STREAM_WARMUP, STREAM_WINDOW = 1, 2, 3
+#: The kind's extra fields of the pool, the warm-up and the window.
+STREAM_POOL_FIELDS, STREAM_WARMUP_FIELDS, STREAM_WINDOW_FIELDS = 4, 5, 6
 
 
 def rng_for(seed: int, stream: int) -> np.random.Generator:
@@ -46,19 +55,37 @@ def centi_ratings(rng: np.random.Generator, n: int, mean: float,
 
 @dataclass(frozen=True)
 class Arrivals:
-    """Requests in send order: ids, ratings and due times (seconds from
-    the start of their phase)."""
+    """Requests in send order: ids, ratings, due times (seconds from the
+    start of their phase) and the kind's extra body fields, one column
+    each under its body key."""
 
     ids: list[str]
     rating: np.ndarray
     due: np.ndarray
+    fields: dict[str, np.ndarray] = field(default_factory=dict)
 
     def __len__(self) -> int:
         return len(self.ids)
 
     def bodies(self) -> list[bytes]:
-        return [f'{{"id":"{pid}","rating":{r:.2f}}}'.encode()
-                for pid, r in zip(self.ids, self.rating.tolist())]
+        if not self.fields:
+            return [f'{{"id":"{pid}","rating":{r:.2f}}}'.encode()
+                    for pid, r in zip(self.ids, self.rating.tolist())]
+        extra = zip(*([f',"{k}":{json.dumps(v)}' for v in col.tolist()]
+                      for k, col in self.fields.items()))
+        return [(f'{{"id":"{pid}","rating":{r:.2f}' + "".join(x)
+                 + "}").encode()
+                for pid, r, x in zip(self.ids, self.rating.tolist(), extra)]
+
+
+def add_fields(phase: list[Arrivals], kind, config: dict, seed: int,
+               stream: int) -> list[Arrivals]:
+    """The requests of one phase, each with the fields the configuration's
+    kind draws for it (``kind.fields(rng, n, config)``), in send order from
+    one stream of ``seed``."""
+    rng = rng_for(seed, stream)
+    return [replace(a, fields=kind.fields(rng, len(a), config))
+            for a in phase]
 
 
 def mix_ratings(rng: np.random.Generator, n: int,
