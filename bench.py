@@ -410,6 +410,7 @@ def bench_e2e(args) -> dict:
         from matchmaking_tpu.service.broker import Properties
         from matchmaking_tpu.service.loadgen import parse_tier_mix
         from matchmaking_tpu.service.overload import stamp_tier
+        from matchmaking_tpu.testing.drain import settle
 
         tier_mix = parse_tier_mix(getattr(args, "e2e_tier_mix", ""))
         cfg = Config(
@@ -506,20 +507,6 @@ def bench_e2e(args) -> dict:
 
         app.broker.basic_consume(reply_q, on_reply, prefetch=1_000_000)
 
-        def quiet() -> bool:
-            # Drained = nothing buffered at ANY stage: broker queues
-            # (request AND reply), handler tasks (deliveries inside a
-            # created-but-unstarted handler are invisible to queue_depth),
-            # the batcher's open window, a flush in progress (covers the
-            # first-window jit compile, during which batcher.depth AND
-            # engine.inflight() both read 0), or windows on device.
-            return (app.broker.queue_depth(cfg.broker.request_queue) == 0
-                    and app.broker.queue_depth(reply_q) == 0
-                    and app.broker.handlers_idle()
-                    and rt.batcher.depth == 0
-                    and rt._flushing == 0
-                    and rt.engine.inflight() == 0)
-
         # Warmup: compile every bucket shape outside the measured phase.
         wrng = np.random.default_rng(4)
         for k, burst in enumerate((8, 40, 160, args.window)):
@@ -531,10 +518,8 @@ def bench_e2e(args) -> dict:
                     Properties(reply_to=reply_q, correlation_id=f"w{k}_{j}",
                                headers={"x-first-received":
                                         f"{time.time():.6f}"}))
-            for _ in range(2400):
-                await asyncio.sleep(0.025)
-                if quiet():
-                    break
+            await settle(app, rt, cfg.broker.request_queue,
+                         reply_queues=(reply_q,))
         lat_ms.clear()
         log("[e2e] buckets warm; starting measured Poisson phases")
 
@@ -598,14 +583,9 @@ def bench_e2e(args) -> dict:
             # Drain: give in-flight windows + replies time to land (the
             # percentiles DO include drained replies — those are real
             # latencies of this phase's requests).
-            drained = False
-            for _ in range(400):
-                await asyncio.sleep(0.025)
-                if quiet():
-                    drained = True
-                    break
-            if not drained:
-                log(f"[e2e {tag}] WARNING: backlog not drained in 10 s — "
+            if not await settle(app, rt, cfg.broker.request_queue,
+                                reply_queues=(reply_q,)):
+                log(f"[e2e {tag}] WARNING: backlog not drained — "
                     "later rows may be contaminated")
             arr = (np.sort(np.asarray(lat_ms)) if lat_ms
                    else np.array([0.0]))
@@ -1373,6 +1353,7 @@ def bench_placement_soak(args) -> dict:
         )
         from matchmaking_tpu.service.app import MatchmakingApp
         from matchmaking_tpu.service.broker import Properties
+        from matchmaking_tpu.testing.drain import settle
 
         import jax
 
@@ -1455,14 +1436,7 @@ def bench_placement_soak(args) -> dict:
             await ctrl.force(kind, "soak.hot", devices,
                              reason=f"soak script {kind} (post-load)")
         # Drain: let in-flight work land.
-        for _ in range(400):
-            await asyncio.sleep(0.025)
-            if (app.broker.queue_depth("soak.hot") == 0
-                    and app.broker.queue_depth(reply_q) == 0
-                    and app.broker.handlers_idle()
-                    and rt.batcher.depth == 0 and rt._flushing == 0
-                    and rt.engine.inflight() == 0):
-                break
+        await settle(app, rt, "soak.hot", reply_queues=(reply_q,))
         waiting = {r.id for r in rt.engine.waiting()}
         dup = sum(n - 1 for n in matched.values() if n > 1)
         lost = submitted - len(matched) - len(waiting)
@@ -1527,6 +1501,7 @@ def bench_crash_soak(args) -> dict:
     )
     from matchmaking_tpu.service.app import MatchmakingApp
     from matchmaking_tpu.service.broker import Properties
+    from matchmaking_tpu.testing.drain import settle
 
     q = "crash.soak"
     pairs = int(args.crash_pairs)
@@ -1599,17 +1574,6 @@ def bench_crash_soak(args) -> dict:
         rng.shuffle(rows)
         return rows
 
-    async def quiesce(app, rt, matched_at_least: int) -> bool:
-        # 5 ms poll: the overhead phase's measured span ends here, and a
-        # coarser tick would quantize the rate it feeds (the soak cycles
-        # share the helper and are insensitive to it).
-        from matchmaking_tpu.testing.drain import fully_drained
-        for _ in range(6000):
-            await asyncio.sleep(0.005)
-            if fully_drained(app, rt, q, matched_at_least):
-                return True
-        return False
-
     async def one_run(run_idx: int) -> dict:
         jdir = tempfile.mkdtemp(prefix=f"mm_crash_soak_r{run_idx}_")
         args._crash_jdir = jdir
@@ -1669,7 +1633,8 @@ def bench_crash_soak(args) -> dict:
                         Properties(reply_to=reply_q, correlation_id=pid))
                     if k % 4 == 3:
                         await asyncio.sleep(gap * 4)
-                ok = await quiesce(app, rt, matched_at_least=2 * pairs)
+                ok = await settle(app, rt, q, matched_at_least=2 * pairs,
+                                  reply_queues=(reply_q,))
                 if not ok:
                     log(f"[crash-soak r{run_idx} c{cycle}] WARNING: "
                         f"quiesce timed out")
@@ -1753,7 +1718,8 @@ def bench_crash_soak(args) -> dict:
                         q,
                         f'{{"id":"w{2 * i + jj}","rating":{r}}}'.encode(),
                         Properties(reply_to="", correlation_id=""))
-            warm_ok = await quiesce(app, rt, matched_at_least=2 * warm_pairs)
+            warm_ok = await settle(app, rt, q,
+                                   matched_at_least=2 * warm_pairs)
             matched0 = app.metrics.counters.get("players_matched")
             t0 = time.perf_counter()
             # Burst-published: the broker drains full bursts, so windows
@@ -1766,7 +1732,7 @@ def bench_crash_soak(args) -> dict:
                         q,
                         f'{{"id":"o{2 * i + j}","rating":{r}}}'.encode(),
                         Properties(reply_to="", correlation_id=""))
-            ok = await quiesce(app, rt, matched_at_least=matched0 + 2 * n)
+            ok = await settle(app, rt, q, matched_at_least=matched0 + 2 * n)
             span = time.perf_counter() - t0
             matched = app.metrics.counters.get("players_matched") - matched0
             await app.stop()
@@ -1866,6 +1832,7 @@ def bench_failover_soak(args) -> dict:
     from matchmaking_tpu.service.app import MatchmakingApp
     from matchmaking_tpu.service.broker import Properties
     from matchmaking_tpu.service.replication import ReplicationHub
+    from matchmaking_tpu.testing.drain import settle
 
     transport = getattr(args, "transport", "inproc")
     if transport == "socket":
@@ -1934,18 +1901,6 @@ def bench_failover_soak(args) -> dict:
         rng = np.random.default_rng(int(args.failover_seed) + cycle)
         rng.shuffle(rows)
         return rows
-
-    async def quiesce(app, rt, standby, matched_at_least: int,
-                      replication: bool = True) -> bool:
-        from matchmaking_tpu.testing.drain import fully_drained
-        for _ in range(6000):
-            await asyncio.sleep(0.005)
-            if standby is not None:
-                standby.pump()
-            if fully_drained(app, rt, q, matched_at_least,
-                             replication=replication):
-                return True
-        return False
 
     async def one_run(run_idx: int) -> dict:
         base_dir = tempfile.mkdtemp(prefix=f"mm_failover_r{run_idx}_")
@@ -2033,8 +1988,8 @@ def bench_failover_soak(args) -> dict:
                         Properties(reply_to=reply_q, correlation_id=pid))
                     if k % 4 == 3:
                         await asyncio.sleep(gap * 4)
-                ok = await quiesce(app, rt, standby,
-                                   matched_at_least=2 * pairs)
+                ok = await settle(app, rt, q, matched_at_least=2 * pairs,
+                                  reply_queues=(reply_q,), standby=standby)
                 if not ok:
                     log(f"[failover-soak r{run_idx} c{cycle}] WARNING: "
                         f"quiesce timed out")
@@ -2053,9 +2008,9 @@ def bench_failover_soak(args) -> dict:
                             .encode(),
                             Properties(reply_to=reply_q,
                                        correlation_id=pid))
-                    ok = await quiesce(app, rt, standby,
-                                       matched_at_least=2 * pairs,
-                                       replication=False)
+                    ok = await settle(app, rt, q, matched_at_least=2 * pairs,
+                                      reply_queues=(reply_q,),
+                                      standby=standby, replication=False)
                     if not ok:
                         log(f"[failover-soak r{run_idx} c{cycle}] "
                             f"WARNING: lag-cycle quiesce timed out")
@@ -2521,7 +2476,7 @@ def bench_incident_soak(args) -> dict:
     from matchmaking_tpu.service.app import MatchmakingApp
     from matchmaking_tpu.service.broker import Properties
     from matchmaking_tpu.service.replication import ReplicationHub
-    from matchmaking_tpu.testing.drain import fully_drained
+    from matchmaking_tpu.testing.drain import settle
 
     q = "incident.soak"
     pairs = int(args.incident_pairs)
@@ -2579,17 +2534,6 @@ def bench_incident_soak(args) -> dict:
                 Properties(reply_to=reply_q, correlation_id=pid))
             await asyncio.sleep(1.0 / rate)
 
-    async def quiesce(app, rt, standby, matched_at_least: int,
-                      replication: bool = True) -> bool:
-        for _ in range(6000):
-            await asyncio.sleep(0.005)
-            if standby is not None:
-                standby.pump()
-            if fully_drained(app, rt, q, matched_at_least,
-                             replication=replication):
-                return True
-        return False
-
     async def wait_capture(app, cls: str, timeout_s: float,
                            standby=None) -> bool:
         """Poll until the recorder has >= 1 bundle of ``cls`` (the
@@ -2633,7 +2577,8 @@ def bench_incident_soak(args) -> dict:
                                      prefetch=1_000_000)
             standby = hub.standby(q, owner="host1")
             await publish_paced(app, reply_q, burst(0))
-            if not await quiesce(app, rt, standby, 2 * pairs):
+            if not await settle(app, rt, q, matched_at_least=2 * pairs,
+                                standby=standby):
                 log(f"[incident-soak r{run_idx} h0] WARNING: quiesce "
                     f"timed out")
             for cls, t in (("slo_burn", 3.0), ("slo_burn_clear", 5.0)):
@@ -2665,8 +2610,8 @@ def bench_incident_soak(args) -> dict:
             await publish_paced(app, reply_q, burst(1))
             # host1 is the terminal primary — no standby drains its
             # stream, so the replication-quiescence clause can't hold.
-            if not await quiesce(app, rt, None, 2 * pairs,
-                                 replication=False):
+            if not await settle(app, rt, q, matched_at_least=2 * pairs,
+                                replication=False):
                 log(f"[incident-soak r{run_idx} h1] WARNING: quiesce "
                     f"timed out")
             for cls, t in (("slo_burn", 3.0), ("slo_burn_clear", 5.0)):

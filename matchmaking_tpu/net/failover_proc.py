@@ -17,7 +17,7 @@ exactly one reply event carrying the command's ``id``.
 Host commands: ``standby`` (attach + pump thread), ``takeover`` (retry
 until the lease actually expires), ``serve`` (boot the app streaming to
 ``target``), ``publish`` (designed load into the local broker, replies
-accumulate ``match_of``), ``quiesce`` (poll ``fully_drained``),
+accumulate ``match_of``), ``quiesce`` (``testing.drain.settle``),
 ``deafen`` (arm an asymmetric partition on the local nemesis),
 ``probe`` (drive both fencing seams and report refusals), ``report``
 (replication watermarks / waiting set / match_of / counters), ``stop``.
@@ -212,17 +212,17 @@ class _HostChild:
         return {"ev": "published", "n": len(msg["rows"])}
 
     async def cmd_quiesce(self, msg) -> dict:
-        from matchmaking_tpu.testing.drain import fully_drained
+        from matchmaking_tpu.testing.drain import settle
 
-        deadline = time.monotonic() + float(msg.get("timeout_s", 30.0))
-        ok = False
-        while time.monotonic() < deadline:
-            await asyncio.sleep(0.005)
-            if fully_drained(self.app, self.rt, self.q,
-                             int(msg.get("matched_at_least", 0)),
-                             replication=bool(msg.get("replication", True))):
-                ok = True
-                break
+        try:
+            ok = await asyncio.wait_for(
+                settle(self.app, self.rt, self.q,
+                       matched_at_least=int(msg.get("matched_at_least", 0)),
+                       reply_queues=(self.reply_q,),
+                       replication=bool(msg.get("replication", True))),
+                float(msg.get("timeout_s", 30.0)))
+        except asyncio.TimeoutError:
+            ok = False
         return {"ev": "quiesced", "ok": ok}
 
     def cmd_deafen(self, msg) -> dict:

@@ -425,6 +425,13 @@ class InProcBroker:
         queue depths."""
         return all(not c._handlers for c in self._consumers.values())
 
+    def unsettled(self, name: str) -> int:
+        """Deliveries of ``name`` handed to a consumer and not yet acked or
+        nacked. A read-only probe for drain checks
+        (``testing.drain.fully_drained``); the served path never calls it."""
+        q = self._queues.get(name)
+        return sum(len(c.unacked) for c in q.consumers) if q else 0
+
     def publish(self, queue: str, body: bytes,
                 properties: Properties | None = None) -> None:
         # AMQP default-exchange semantics: publishing to a queue that does

@@ -90,7 +90,6 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
                        seed: int, deadline_s: float = 0.0,
                        tier_mix: "dict[int, float] | None" = None,
                        reply_q: str = "loadgen.replies",
-                       drain_polls: int = 200,
                        quality_stats: bool = False,
                        rating_sigma: float | None = None,
                        scenario=None, rate_scale: float = 1.0,
@@ -127,6 +126,7 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
     """
     from matchmaking_tpu.service.broker import Properties
     from matchmaking_tpu.service.overload import stamp_deadline, stamp_tier
+    from matchmaking_tpu.testing.drain import settle
 
     arrivals = None
     if scenario is not None:
@@ -320,22 +320,14 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
         if i < n_max and sched[i] > now_rel:
             await asyncio.sleep(min(sched[i] - now_rel, 0.005))
     span = time.perf_counter() - t0
-    for _ in range(drain_polls):
-        await asyncio.sleep(0.025)
-        if retry_tasks:
-            # Late sheds during the drain can still schedule retries —
-            # let them publish before judging the broker quiet.
-            pending = [tk for tk in retry_tasks if not tk.done()]
-            if pending:
-                await asyncio.gather(*pending, return_exceptions=True)
-                continue
-        if (app.broker.queue_depth(queue) == 0
-                and app.broker.handlers_idle()):
-            break
-    # A shed reply consumed on the drain loop's last poll can still have
-    # scheduled a retry whose sleep outlives the loop — cancel and reap
-    # so no task publishes after the reply consumer is gone (and no
-    # "Task was destroyed but it is pending" lands at loop close).
+    # Late sheds during the drain can still schedule retries: the drain
+    # holds until every retry has published, then until it settles.
+    await settle(app, app.runtime(queue), queue, reply_queues=(reply_q,),
+                 until=lambda: all(tk.done() for tk in retry_tasks))
+    # A drain cut by its hang guard can leave a retry whose sleep outlives
+    # it — cancel and reap so no task publishes after the reply consumer
+    # is gone (and no "Task was destroyed but it is pending" lands at
+    # loop close).
     for tk in retry_tasks:
         if not tk.done():
             tk.cancel()

@@ -45,6 +45,7 @@ from matchmaking_tpu.service.attribution import (
     decompose,
 )
 from matchmaking_tpu.service.broker import Properties
+from matchmaking_tpu.testing.drain import settle
 from matchmaking_tpu.utils.timeseries import SloMonitor, TelemetryRing
 
 
@@ -419,12 +420,7 @@ async def test_telemetry_reset_survives_engine_revive_mid_soak(rng):
                     f'{{"id":"p{wave}_{j}","rating":{rating}}}'.encode(),
                     Properties(reply_to="reset.replies",
                                correlation_id=f"c{wave}_{j}"))
-            for _ in range(200):
-                await asyncio.sleep(0.02)
-                if (app.broker.queue_depth(q.name) == 0
-                        and rt.batcher.depth == 0 and rt._flushing == 0
-                        and rt.engine.inflight() == 0):
-                    break
+            await settle(app, rt, q.name)
             app.sample_telemetry(now=float(wave + 1))
             crash_seen = crash_seen or any(
                 e["kind"] == "engine_crash"

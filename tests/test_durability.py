@@ -40,6 +40,7 @@ from matchmaking_tpu.config import (
 )
 from matchmaking_tpu.service.broker import Properties
 from matchmaking_tpu.service.contract import SearchRequest
+from matchmaking_tpu.testing.drain import settle
 from matchmaking_tpu.utils import journal as jr
 
 pytestmark = pytest.mark.durability
@@ -79,22 +80,6 @@ def durable_cfg(jdir, *, chaos=None, mesh=1, bucketed=False,
                                     compact_interval_s=compact_interval),
         chaos=chaos if chaos is not None else ChaosConfig(),
     )
-
-
-async def _quiesce(app, rt, *, matched_at_least=0, tries=600):
-    """Deterministic drain (the PR 2 soak pattern): nothing buffered at
-    any stage AND the matched floor reached — never a bare sleep."""
-    for _ in range(tries):
-        await asyncio.sleep(0.025)
-        if (app.metrics.counters.get("players_matched") >= matched_at_least
-                and app.broker.queue_depth(Q) == 0
-                and app.broker.handlers_idle()
-                and rt.batcher.depth == 0
-                and rt._flushing == 0
-                and (not hasattr(rt.engine, "inflight")
-                     or rt.engine.inflight() == 0)):
-            return True
-    return False
 
 
 def _publish(app, pid, rating, reply_q):
@@ -312,7 +297,8 @@ async def _run_crash_cycle(jdir, *, chaos=None):
                         ("p2", 2000.0), ("p3", 2001.0),
                         ("s0", 4000.0)):
         _publish(app, pid, rating, "dur.replies")
-    assert await _quiesce(app, rt, matched_at_least=4)
+    assert await settle(app, rt, Q, matched_at_least=4,
+                        reply_queues=("dur.replies",))
     waiting = {r.id for r in rt.engine.waiting()}
     matches = {r["player_id"]: r["match"]["match_id"]
                for r in replies if r.get("status") == "matched"}
@@ -354,7 +340,7 @@ async def test_crash_recovery_service_roundtrip(tmp_path):
                             ("p2", 2000.0), ("p3", 2001.0),
                             ("s0", 4000.0)):
             _publish(app2, pid, rating, "dur.replays")
-        assert await _quiesce(app2, rt2)
+        assert await settle(app2, rt2, Q, reply_queues=("dur.replays",))
         replayed = {r["player_id"]: r["match"]["match_id"]
                     for r in replays if r.get("status") == "matched"}
         assert replayed == matches  # byte-for-byte the cached truth
@@ -372,7 +358,7 @@ async def test_clean_shutdown_skips_recovery(tmp_path):
     await app.start()
     rt = app.runtime(Q)
     _publish(app, "s0", 4000.0, "")
-    assert await _quiesce(app, rt)
+    assert await settle(app, rt, Q)
     await app.stop()  # graceful: clean marker written
     app2 = MatchmakingApp(durable_cfg(jdir))
     await app2.start()
@@ -418,7 +404,7 @@ async def test_bucketed_index_exact_after_replay(tmp_path):
     # buckets and never match.
     for i, rating in enumerate((800.0, 1500.0, 2200.0, 4000.0)):
         _publish(app, f"s{i}", rating, "")
-    assert await _quiesce(app, rt)
+    assert await settle(app, rt, Q)
     pre = {r.id for r in rt.engine.waiting()}
     assert len(pre) == 4
     await app.crash()
@@ -490,7 +476,8 @@ async def test_device_lost_failover_demotes_to_surviving_devices(tmp_path):
         # The first device step raises ChaosDeviceLostError: the window
         # nacks, the queue demotes D=2 -> D=1 onto the surviving device,
         # and the redelivered pair matches on the demoted engine.
-        assert await _quiesce(app, rt, matched_at_least=2)
+        assert await settle(app, rt, Q, matched_at_least=2,
+                            reply_queues=("fo.replies",))
         assert rt.placement == (0,)
         assert app.metrics.counters.get("device_failovers") == 1
         assert len(rt.failover_log) == 1
@@ -505,7 +492,8 @@ async def test_device_lost_failover_demotes_to_surviving_devices(tmp_path):
         # Traffic keeps flowing on the demoted binding.
         _publish(app, "b0", 1600.0, "fo.replies")
         _publish(app, "b1", 1601.0, "fo.replies")
-        assert await _quiesce(app, rt, matched_at_least=4)
+        assert await settle(app, rt, Q, matched_at_least=4,
+                            reply_queues=("fo.replies",))
     finally:
         await app.stop()
 

@@ -50,7 +50,7 @@ from matchmaking_tpu.config import (
 from matchmaking_tpu.service.app import MatchmakingApp
 from matchmaking_tpu.service.broker import Properties
 from matchmaking_tpu.service.replication import ReplicationHub
-from matchmaking_tpu.testing.drain import fully_drained
+from matchmaking_tpu.testing.drain import settle
 from matchmaking_tpu.utils.forensics import (
     DETERMINISTIC_KINDS,
     INCIDENT_SCHEMA,
@@ -90,18 +90,6 @@ def _publish(app, pid, rating, reply_q):
     app.broker.publish(
         Q, json.dumps({"id": pid, "rating": rating}).encode(),
         Properties(reply_to=reply_q, correlation_id=pid))
-
-
-async def _quiesce(app, rt, *, matched_at_least=0, standby=None,
-                   replication=True, tries=2400):
-    for _ in range(tries):
-        await asyncio.sleep(0.025)
-        if standby is not None:
-            standby.pump()
-        if fully_drained(app, rt, Q, matched_at_least,
-                         replication=replication):
-            return True
-    return False
 
 
 # ---- event spine ------------------------------------------------------------
@@ -359,7 +347,7 @@ async def test_debug_incidents_and_prom_concurrent_after_failover(tmp_path):
     standby = hub.standby(Q, owner="hostB")
     for i in range(4):
         _publish(app, f"fp{i}", 1500.0 + (i // 2) * 400.0, reply)
-    assert await _quiesce(app, rt, matched_at_least=4, standby=standby)
+    assert await settle(app, rt, Q, matched_at_least=4, standby=standby)
     await app.crash()
     standby.takeover(time.monotonic() + 0.4 + 0.05)
 
@@ -404,8 +392,8 @@ async def test_debug_incidents_and_prom_concurrent_after_failover(tmp_path):
             assert validate_bundle(json.loads(text)) == []
             status, _ = await scrape(s, "/debug/incidents?id=inc-999999")
             assert status == 404
-        assert await _quiesce(app2, rt2 := app2.runtime(Q),
-                              matched_at_least=6, replication=False)
+        assert await settle(app2, rt2 := app2.runtime(Q), Q,
+                            matched_at_least=6, replication=False)
         spine_rows = app2.spine.window()
         assert [r["seq"] for r in spine_rows] == sorted(
             r["seq"] for r in spine_rows)
@@ -437,7 +425,7 @@ async def test_capture_during_drain_blocks_nothing_leaks_nothing(tmp_path):
         t.start()
         t.join()
         assert app.incidents.by_class.get("breaker_trip") == 1
-        assert await _quiesce(app, rt, matched_at_least=8)
+        assert await settle(app, rt, Q, matched_at_least=8)
         assert app.metrics.counters.get("players_matched") == 8
         assert app.incidents.dropped == 0
     finally:
@@ -532,7 +520,7 @@ async def test_journal_dump_lsn_range_slices_bundle_window(tmp_path):
     try:
         for i in range(4):
             _publish(app, f"jp{i}", 1200.0 + (i // 2) * 300.0, reply)
-        assert await _quiesce(app, app.runtime(Q), matched_at_least=4)
+        assert await settle(app, app.runtime(Q), Q, matched_at_least=4)
         bundle = app.incidents.capture(
             "breaker_trip",
             app.events.append("engine_revive", Q, "fixture anchor"))

@@ -35,6 +35,7 @@ from matchmaking_tpu.config import (
 from matchmaking_tpu.service.app import MatchmakingApp
 from matchmaking_tpu.service.broker import InProcBroker, Properties
 from matchmaking_tpu.service.ingress import ShardedRecent, shard_of
+from matchmaking_tpu.testing.drain import settle
 
 pytestmark = pytest.mark.ingress
 
@@ -113,14 +114,6 @@ async def _run_soak(cfg: Config) -> tuple[dict, dict]:
 
     app.broker.basic_consume(REPLY, on_reply, prefetch=1_000_000)
 
-    def quiet() -> bool:
-        return (app.broker.queue_depth(QUEUE) == 0
-                and app.broker.queue_depth(REPLY) == 0
-                and app.broker.handlers_idle()
-                and rt.batcher.depth == 0
-                and rt._flushing == 0
-                and rt.engine.inflight() == 0)
-
     try:
         bodies = _soak_bodies()
         for b in range(BURSTS):
@@ -128,11 +121,8 @@ async def _run_soak(cfg: Config) -> tuple[dict, dict]:
                 app.broker.publish(
                     QUEUE, bodies[i],
                     Properties(reply_to=REPLY, correlation_id=f"c{i}"))
-            for _ in range(400):
-                await asyncio.sleep(0.01)
-                if quiet():
-                    break
-            assert quiet(), f"burst {b} did not drain"
+            assert await settle(app, rt, QUEUE, reply_queues=(REPLY,)), (
+                f"burst {b} did not drain")
         counters = {
             name: int(app.metrics.counters.get(name))
             for name in ("players_matched", "rejected_by_middleware",
@@ -232,13 +222,7 @@ def test_consume_batch_chaos_redelivery_soak():
                 if i % 40 == 39:
                     await asyncio.sleep(0.05)
             rt = app.runtime(q.name)
-            for _ in range(600):
-                await asyncio.sleep(0.025)
-                if (app.broker.queue_depth(q.name) == 0
-                        and app.broker.handlers_idle()
-                        and rt.batcher.depth == 0 and rt._flushing == 0
-                        and rt.engine.inflight() == 0):
-                    break
+            await settle(app, rt, q.name, reply_queues=(REPLY,))
             matched = sum("matched" in s for s in statuses.values())
             waiting = rt.engine.pool_size()
             assert matched + waiting >= n - 2, (matched, waiting)

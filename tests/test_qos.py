@@ -35,6 +35,7 @@ from matchmaking_tpu.service.overload import (
     stamp_tier,
     tier_of,
 )
+from matchmaking_tpu.testing.drain import settle
 
 pytestmark = [pytest.mark.qos, pytest.mark.overload]
 
@@ -277,14 +278,8 @@ async def _qos_soak_run() -> dict:
     await app.start()
     rt = app.runtime(q.name)
     try:
-        for _ in range(400):
-            await asyncio.sleep(0.05)
-            if (rt.engine.pool_size() >= _W
-                    and app.broker.queue_depth(q.name) == 0
-                    and app.broker.handlers_idle()
-                    and rt.batcher.depth == 0
-                    and rt._flushing == 0):
-                break
+        await settle(app, rt, q.name,
+                     until=lambda: rt.engine.pool_size() >= _W)
         replies = await _drain_replies(app, reply)
         ac = rt.admission
         assert ac is not None

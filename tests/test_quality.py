@@ -35,6 +35,7 @@ from matchmaking_tpu.engine.quality import (
 from matchmaking_tpu.service.app import MatchmakingApp
 from matchmaking_tpu.service.broker import Properties
 from matchmaking_tpu.service.contract import RequestColumns
+from matchmaking_tpu.testing.drain import settle
 
 pytestmark = pytest.mark.quality
 
@@ -380,10 +381,7 @@ async def test_service_soak_waited_ms_and_device_host_reconciliation(
     await app.start()
     ratings = await _publish_soak(app, q, reply)
     rt = app.runtime(q.name)
-    await _wait_for(lambda: app.broker.queue_depth(q.name) == 0
-                    and app.broker.handlers_idle()
-                    and rt.batcher.depth == 0 and rt._flushing == 0
-                    and rt.engine.inflight() == 0)
+    assert await settle(app, rt, q.name, reply_queues=(reply,))
     try:
         assert len(matched) >= 100, "soak formed too few matches"
         # Wire contract: waited_ms on every matched body, <= latency_ms.
@@ -539,10 +537,7 @@ async def _chaos_quality_run() -> tuple[dict, dict]:
             Properties(reply_to=reply, correlation_id=f"c{i}",
                        headers={"x-first-received": f"{now - 1.0:.6f}"}))
     rt = app.runtime(q.name)
-    await _wait_for(lambda: app.broker.queue_depth(q.name) == 0
-                    and app.broker.handlers_idle()
-                    and rt.batcher.depth == 0 and rt._flushing == 0
-                    and rt.engine.inflight() == 0)
+    assert await settle(app, rt, q.name, reply_queues=(reply,))
     async with rt._engine_lock:
         await asyncio.to_thread(rt.engine.flush)
     rep = rt.engine.quality_report()

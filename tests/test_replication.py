@@ -62,7 +62,7 @@ from matchmaking_tpu.service.replication import (
     StandbyApplier,
     baseline_payload,
 )
-from matchmaking_tpu.testing.drain import fully_drained
+from matchmaking_tpu.testing.drain import fully_drained, settle
 from matchmaking_tpu.utils import journal as jr
 from matchmaking_tpu.utils.journal import FencedError
 
@@ -105,21 +105,6 @@ def _collect_responses(app, reply_q, sink):
 
     app.broker.declare_queue(reply_q)
     app.broker.basic_consume(reply_q, on_reply, prefetch=1_000_000)
-
-
-async def _quiesce(app, rt, *, matched_at_least=0, standby=None,
-                   replication=True, tries=2400):
-    """The soak drain with the standby in the loop: the replication-
-    quiescence clause only settles when the standby PUMPS (applies +
-    acks), so a drain that forgets the standby would hang by design."""
-    for _ in range(tries):
-        await asyncio.sleep(0.025)
-        if standby is not None:
-            standby.pump()
-        if fully_drained(app, rt, Q, matched_at_least,
-                         replication=replication):
-            return True
-    return False
 
 
 # ---- lease / epoch authority ------------------------------------------------
@@ -369,7 +354,8 @@ async def test_replication_service_roundtrip_and_clean_handoff(tmp_path):
                             ("p2", 2000.0), ("p3", 2001.0),
                             ("s0", 4000.0)):
             _publish(app, pid, rating, "repl.replies")
-        assert await _quiesce(app, rt, matched_at_least=4, standby=standby)
+        assert await settle(app, rt, Q, matched_at_least=4,
+                            reply_queues=("repl.replies",), standby=standby)
         standby.pump()
         # The shadow mirrors the primary: the lone unmatched player
         # waiting, every matched player in the dedup cache, the apply
@@ -414,12 +400,12 @@ async def test_drain_holds_until_replication_quiesces(tmp_path):
         _collect_responses(app, "drain.replies", replies)
         _publish(app, "a0", 1500.0, "drain.replies")
         _publish(app, "a1", 1501.0, "drain.replies")
-        assert await _quiesce(app, rt, matched_at_least=2,
-                              replication=False)
+        assert await settle(app, rt, Q, matched_at_least=2,
+                            replication=False)
         assert not fully_drained(app, rt, Q, 2)          # unacked tail
         assert fully_drained(app, rt, Q, 2, replication=False)
-        assert await _quiesce(app, rt, matched_at_least=2,
-                              standby=standby)           # clause settles
+        assert await settle(app, rt, Q, matched_at_least=2,
+                            standby=standby)             # clause settles
         assert rt.replication.quiescent
     finally:
         await app.stop()
@@ -447,7 +433,8 @@ async def test_failover_crash_takeover_successor_adopts(tmp_path):
     _collect_responses(app, "fo.replies", replies)
     for pid, rating in (("p0", 1500.0), ("p1", 1501.0), ("s0", 4000.0)):
         _publish(app, pid, rating, "fo.replies")
-    assert await _quiesce(app, rt, matched_at_least=2, standby=standby)
+    assert await settle(app, rt, Q, matched_at_least=2,
+                        reply_queues=("fo.replies",), standby=standby)
 
     await app.crash()
     # Lease expiry is scriptable: the authority's clock is the caller's.
@@ -485,7 +472,8 @@ async def test_failover_crash_takeover_successor_adopts(tmp_path):
         replies.clear()
         _collect_responses(app2, "fo.replies", replies)
         _publish(app2, "p0", 1500.0, "fo.replies")
-        assert await _quiesce(app2, rt2, replication=False)
+        assert await settle(app2, rt2, Q, reply_queues=("fo.replies",),
+                            replication=False)
         replayed = [r for r in replies if r.get("player_id") == "p0"]
         assert replayed and replayed[0]["status"] == "matched"
     finally:
@@ -575,8 +563,8 @@ async def test_sanitizer_flags_publish_after_fence_and_healthz_degraded(
             _collect_responses(app, "fence.replies", replies)
             _publish(app, "a0", 1500.0, "fence.replies")
             _publish(app, "a1", 1501.0, "fence.replies")
-            assert await _quiesce(app, rt, matched_at_least=2,
-                                  standby=standby)
+            assert await settle(app, rt, Q, matched_at_least=2,
+                                standby=standby)
             assert not [f for f in san.findings
                         if f.kind.startswith("replication-")]
             standby.takeover(time.monotonic() + 0.4 + 0.05)

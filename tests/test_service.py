@@ -21,7 +21,7 @@ from matchmaking_tpu.service.app import MatchmakingApp
 from matchmaking_tpu.service.batcher import Batcher
 from matchmaking_tpu.service.broker import Delivery, InProcBroker, Properties
 from matchmaking_tpu.service.client import MatchmakingClient
-from matchmaking_tpu.testing.drain import fully_drained
+from matchmaking_tpu.testing.drain import settle
 from matchmaking_tpu.service.middleware import (
     AuthMiddleware,
     DecodeMiddleware,
@@ -332,18 +332,10 @@ async def test_e2e_duplicate_delivery_never_double_matches():
     client = MatchmakingClient(app.broker, "matchmaking.search")
     n = 8
     replies = {f"p{i}": client.submit({"id": f"p{i}", "rating": 1500 + i}) for i in range(n)}
-    # Deterministic drain (the PR 2 soak pattern, ISSUE 15 satellite): the
-    # old fixed 0.3 s sleep raced the duplicate redeliveries on the 1-core
-    # box (PR 14 reproduced the flake on the unmodified tree). The break
-    # condition mirrors the assertions below — every player matched AND
-    # nothing is buffered at ANY stage, so every duplicate has been
-    # consumed and its replay response published (the same predicate the
-    # crash-soak quiesce polls; extended in one place as stages grow).
+    # The replies are read only once every duplicate has been consumed
+    # and its replay published.
     rt = app.runtime("matchmaking.search")
-    for _ in range(400):
-        await asyncio.sleep(0.025)
-        if fully_drained(app, rt, "matchmaking.search", n):
-            break
+    await settle(app, rt, "matchmaking.search", matched_at_least=n)
     match_ids = {}
     for pid, reply_to in replies.items():
         while True:
