@@ -10,7 +10,7 @@ WORKDIR /app
 COPY matchmaking_tpu/ matchmaking_tpu/
 COPY native/ native/
 COPY configs/ configs/
-COPY bench.py README.md ./
+COPY README.md ./
 
 # Native codec: build ahead of time when a toolchain is present (the Python
 # binding also builds lazily at first use and falls back to pure Python).

@@ -152,15 +152,11 @@ class SourceFile:
 #: Directories (repo-relative) the analyzer walks. Rule modules narrow
 #: further via path predicates (e.g. blocking-call scans the package only).
 DEFAULT_SCAN_DIRS = ("matchmaking_tpu", "scripts", "tests")
-DEFAULT_SCAN_FILES = ("bench.py",)
 _SKIP_PARTS = {"__pycache__", ".git"}
 
 
 def discover(root: str) -> list[SourceFile]:
     out: list[SourceFile] = []
-    for rel in DEFAULT_SCAN_FILES:
-        if os.path.isfile(os.path.join(root, rel)):
-            out.append(SourceFile(root, rel))
     for base in DEFAULT_SCAN_DIRS:
         top = os.path.join(root, base)
         if not os.path.isdir(top):

@@ -77,8 +77,7 @@ _SEED_REQUIRED = {"random.Random", "np.random.default_rng",
 
 def _in_scope(sf: SourceFile) -> bool:
     return (sf.path.startswith(("matchmaking_tpu/", "tests/", "scripts/"))
-            or sf.path == "bench.py") and not sf.path.startswith(
-                "matchmaking_tpu/analysis/")
+            and not sf.path.startswith("matchmaking_tpu/analysis/"))
 
 
 def _contains_time_time(node: ast.AST) -> ast.Call | None:

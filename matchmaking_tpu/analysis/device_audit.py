@@ -4,8 +4,8 @@ The recompile rule (recompile.py) proves each kernel family's TRACE is
 stable; this rule audits what the trace actually DOES.  Everything here is
 trace-only — ``jax.make_jaxpr`` / ``jax.eval_shape`` under the canonical
 small configs recompile.py already defines — so the lint node never
-initializes a TPU backend and never executes a kernel (BENCH_CONFIGS.md:
-lint stays off the bench path).
+initializes a TPU backend and never executes a kernel (lint stays off
+the benchmark's path).
 
 Static half (AST over the kernel/engine modules):
 

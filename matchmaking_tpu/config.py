@@ -120,8 +120,7 @@ class EngineConfig:
     #: O(P/D + N·D) per device, bit-identical to the fallback while pool
     #: occupancy stays <= N (the host checks per window and silently falls
     #: back above it, counted in engine_counters team_ring_fallback). Size
-    #: N at the expected concurrent WAITING population, not capacity
-    #: (``bench.py --comms`` prints both paths' ICI bytes per D).
+    #: N at the expected concurrent WAITING population, not capacity.
     team_ring_k: int = 0
     #: Max dispatched-but-uncollected windows the SERVICE keeps in flight on
     #: the pipelined columnar path (1 = the old dispatch-then-block flush).
@@ -151,7 +150,7 @@ class EngineConfig:
     #: kernels.KernelSet); without warmup the first window that needs the
     #: OTHER variant stalls on an XLA compile inline on the serving path —
     #: the recompile cliff the bucketing exists to prevent. Off by default
-    #: (tests build many small engines; serve/bench turn it on).
+    #: (tests build many small engines; deployment configs turn it on).
     warm_start: bool = False
     #: Rating-banded candidate pruning (single-device 1v1 path). 0 = dense
     #: scoring of every pool block. N > 0: each rating-sorted window chunk

@@ -26,8 +26,8 @@ replays a bit-identical decision trace — what the seeded acceptance test
 
 ``tuned_config()`` exports the converged knob values as a committed
 capacity artifact (``configs/tuned/<scenario>.json`` — written by
-``bench.py --scenario-matrix``): the "at this workload, run these knobs"
-half of the capacity-planning story.
+``service.loadgen.scenario_matrix``): the "at this workload, run these
+knobs" half of the capacity-planning story.
 """
 
 from __future__ import annotations
@@ -446,7 +446,7 @@ class AutoTuner:
             "scenario": scenario,
             "seed": seed,
             "target_p99_ms": self.target_p99_ms,
-            "generated_by": "bench.py --scenario-matrix (AutoTuner)",
+            "generated_by": "loadgen.scenario_matrix (AutoTuner)",
             "moves": self.moves,
             "knobs": self.knobs(),
         }

@@ -26,8 +26,6 @@ network layer under them:
   the same transport, with renewal deadlines that budget for RTT (a
   renewal in flight when the lease expires must NOT count — fencing
   safety over liveness).
-- :mod:`~matchmaking_tpu.net.failover_proc` — the child-process runner
-  behind ``bench.py --failover-soak --transport=socket``.
 """
 
 from matchmaking_tpu.net.lease import LeaseService, RemoteLeaseAuthority

@@ -3,8 +3,8 @@
 Every bench number so far came from ``offered_load()``'s Poisson arrivals
 with iid (or paired) ratings — no diurnal peaks, no rating-skewed cohorts,
 no retry storms. This module generalizes that seeded core into a declarative
-**scenario spec** the loadgen, the bench matrix (``bench.py
---scenario-matrix``) and the soak tests all share:
+**scenario spec** the loadgen, its matrix (``loadgen.scenario_matrix``)
+and the soak tests all share:
 
 - **Segments** — a piecewise arrival-rate curve (steady / ramp / diurnal /
   flash), concatenated in time. Arrival times are drawn by the inhomogeneous-

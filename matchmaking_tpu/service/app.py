@@ -215,7 +215,7 @@ class _QueueRuntime:
         self.failover_log: list[dict] = []
         #: The last hard-crash recovery this runtime applied (None = clean
         #: boot): rto_ms + the journal's deterministic transcript — what
-        #: bench.py --crash-soak pins bit-identical across two runs.
+        #: test_durability.py pins bit-identical across two runs.
         self.last_recovery: "dict | None" = None
         self._bind_engine(self._make_engine())
         # At-least-once dedup: player id → (encoded terminal response BODY,
@@ -3664,8 +3664,8 @@ class _QueueRuntime:
             self.replication.shutdown(time.monotonic())
 
     def abandon(self) -> None:
-        """Crash-fidelity teardown (bench --crash-soak / durability
-        tests): cancel the timers and drop the journal WITHOUT a clean
+        """Crash-fidelity teardown (durability tests): cancel the timers
+        and drop the journal WITHOUT a clean
         marker, drain, or final commit — the on-disk journal state is
         exactly what a ``kill -9`` would leave. The engine is still
         closed (device buffers are process resources a soak would
@@ -3957,7 +3957,7 @@ class MatchmakingApp:
             self._owns_net_hub = False
 
     async def crash(self) -> None:
-        """Simulated HARD crash (bench --crash-soak / durability tests):
+        """Simulated HARD crash (durability tests):
         tear the process state down with NO drain, NO checkpoints, and NO
         clean-shutdown journal markers — in-flight windows are dropped,
         uncommitted journal buffers are lost, consumers die with the

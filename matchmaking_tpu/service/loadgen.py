@@ -7,16 +7,15 @@ multi-process ingress benchmark cannot drive N workers through a shared
 network broker. Each worker instead drives itself — the full ingress path
 (broker → decode → middleware → batcher → engine → publish) runs in-process,
 which is exactly the per-consumer work the reference fans out across AMQP
-consumers. The supervisor-level bench (bench.py --multiproc phase) spawns N
-of these via WorkerSupervisor and sums the per-worker throughput.
+consumers. ``service.multiproc.WorkerSupervisor`` can spawn N of these
+(tests/test_multiproc.py) and sum the per-worker throughput.
 
 Overload mode (``--offered-rate``, ISSUE 5): the offered rate may exceed
 the service's clearing rate on purpose — the report then accounts for every
 response class (matched / queued / shed / timeout / error) instead of only
 matches, and stamps per-request deadlines (``--deadline-ms``) so the
 deadline-propagation path is exercised. The seeded overload soak
-(tests/test_overload.py) and bench.py's multiproc phase both drive this
-entry point.
+(tests/test_overload.py) drives this entry point.
 
 Tiered mode (``--tier-mix``, ISSUE 7): offer a per-class load — e.g.
 ``0:0.2,1:0.5,2:0.3`` sends 20% tier-0 / 50% tier-1 / 30% tier-2, each
@@ -36,8 +35,13 @@ seeded — while the retry send time follows the reply, which is behavior,
 not transcript). ``scenario="steady"`` reduces to the legacy model byte
 for byte (tests/test_scenario.py pins it).
 
-Env contract (set by the bench on top of the multiproc worker env; each has
-a CLI flag that wins when both are given):
+Scenario matrix (``scenario_matrix``): one fresh app per named scenario,
+each driven by ``offered_load(..., scenario=...)`` with the autotuner
+closing the loop, one artifact per cell; ``tuned_dir`` writes the
+converged knobs (the ``configs/tuned/`` files).
+
+Env contract (set on top of the multiproc worker env; each has a CLI flag
+that wins when both are given):
     MM_LOADGEN_RATE         offered req/s (Poisson)      (--offered-rate)
     MM_LOADGEN_SECONDS      measured duration            (--seconds)
     MM_LOADGEN_SEED         arrival/rating RNG seed      (--seed)
@@ -54,10 +58,13 @@ from __future__ import annotations
 
 import asyncio
 import json
+import logging
 import os
 import time
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 #: Response classes tallied from reply bodies (cheap substring probes — at
 #: overload rates a full json.loads per reply would bill the loadgen, not
@@ -95,7 +102,7 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
                        scenario=None, rate_scale: float = 1.0,
                        time_scale: float = 1.0) -> dict:
     """Offer a seeded Poisson load to ``app``'s broker and account for
-    every response class. Reusable by the CLI below, bench.py's workers,
+    every response class. Reusable by the CLI below, the scenario matrix
     and the overload soak (tests/test_overload.py) — one load driver, not
     three drifting copies.
 
@@ -396,7 +403,7 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
     if arrivals is not None:
         result["scenario"] = scenario.name
         # Replay pin: pure function of (seed, scenario, scales) — two runs
-        # of the same cell must agree (the bench matrix smoke asserts it).
+        # of the same cell must agree (the matrix smoke asserts it).
         result["scenario_digest"] = arrivals.digest()
         result["duration_s"] = round(duration, 3)
         result["retries_sent"] = retries_sent
@@ -405,6 +412,209 @@ async def offered_load(app, queue: str, *, rate: float, duration: float,
             for j, row in sorted(per_cohort.items())
         }
     return result
+
+
+async def _scenario_cell(scn, *, seed: int, rate_scale: float,
+                         time_scale: float, slo_ms: float, wait_ms: float,
+                         max_waiting: int, trajectory: int,
+                         no_autotune: bool) -> "tuple[dict, dict | None]":
+    """One matrix cell: a fresh single-queue app driven by one scenario's
+    seeded population load, with the autotuner closing the loop (unless
+    ``no_autotune``). The cell artifact is the full observability story —
+    telemetry-ring trajectory, attribution shares, per-tier SLO
+    attainment, quality, shed/expired, autotuner audit — not just a
+    throughput number. Returns it with the autotuner's converged knobs
+    (None without the autotuner)."""
+    from matchmaking_tpu.config import (
+        AutotuneConfig,
+        BatcherConfig,
+        BrokerConfig,
+        ChaosConfig,
+        Config,
+        EngineConfig,
+        ObservabilityConfig,
+        OverloadConfig,
+        QueueConfig,
+    )
+    from matchmaking_tpu.service.app import MatchmakingApp
+
+    q = "matchmaking.search"
+    tiers = scn.max_tier + 1 if scn.tiered else 1
+    has_deadlines = any(c.deadline_ms > 0 for c in scn.cohorts)
+    chaos = scn.chaos_config(q, seed=seed)
+    cfg = Config(
+        queues=(QueueConfig(rating_threshold=100.0,
+                            send_queued_ack=False),),
+        engine=EngineConfig(
+            backend="tpu", pool_capacity=8192, pool_block=2048,
+            batch_buckets=(16, 64, 256), top_k=8, pipeline_depth=2,
+            warm_start=True),
+        # The cell's STATIC base config is deliberately mid-range (window
+        # wait included) — the point of the matrix is watching the tuner
+        # move it per workload, and diffing the converged knobs.
+        batcher=BatcherConfig(max_batch=256, max_wait_ms=wait_ms),
+        broker=BrokerConfig(prefetch=8192),
+        overload=OverloadConfig(
+            max_waiting=max_waiting,
+            tiers=tiers,
+            deadline_sweep_ms=(25.0 if has_deadlines else 0.0)),
+        chaos=chaos if chaos is not None else ChaosConfig(),
+        observability=ObservabilityConfig(
+            slo_target_ms=slo_ms, slo_objective=0.99,
+            slo_fast_window_s=1.0, slo_slow_window_s=4.0,
+            snapshot_interval_s=0.25),
+        autotune=(AutotuneConfig() if no_autotune
+                  else AutotuneConfig(interval_s=0.25,
+                                      max_wait_ms_min=1.0)),
+    )
+    app = MatchmakingApp(cfg)
+    try:
+        await app.start()
+        res = await offered_load(
+            app, q, rate=0.0, duration=0.0, seed=seed,
+            scenario=scn, rate_scale=rate_scale, time_scale=time_scale)
+        app.sample_telemetry()  # final trajectory point before teardown
+        attr_q = app.attribution.snapshot()["queues"].get(q, {})
+        cats = attr_q.get("categories") or {}
+        hist = app.metrics.stages.get(q, {}).get("total")
+        cell: dict = {
+            "scenario": scn.name,
+            "seed": seed,
+            "rate_scale": rate_scale,
+            "time_scale": time_scale,
+            "duration_s": res.get("duration_s"),
+            "scenario_digest": res.get("scenario_digest"),
+            "offered": res["sent"],
+            "sent_req_s": res["sent_req_s"],
+            "matched": res["players_matched"],
+            "matched_per_s": res["matched_per_s"],
+            "queued_acks": res["queued_acks"],
+            "shed": res["shed_requests"],
+            "expired": res["expired_requests"],
+            "retries_sent": res.get("retries_sent", 0),
+            "cohorts": res.get("cohorts"),
+            "slo_target_ms": slo_ms,
+            "slo_attainment": attr_q.get("slo_attainment"),
+            "admitted_p99_ms": (round(hist.percentile(99) * 1e3, 3)
+                                if hist is not None and hist.count
+                                else None),
+            "attribution": {
+                name: {"kind": cat.get("kind"), "share": cat.get("share")}
+                for name, cat in cats.items()
+            },
+        }
+        if tiers > 1:
+            per_tier = {}
+            for t in range(tiers):
+                good, total = app.attribution.slo_counts_tier(q, t)
+                per_tier[str(t)] = {
+                    "slo_good": good, "slo_total": total,
+                    "attainment": (round(good / total, 4) if total
+                                   else None),
+                    "shed": int(app.metrics.counters.get(
+                        f"shed_requests_t{t}")),
+                    "expired": int(app.metrics.counters.get(
+                        f"expired_requests_t{t}")),
+                }
+            cell["tiers"] = per_tier
+        qentry = (app.quality.snapshot(q).get("queues") or {}).get(q)
+        if qentry:
+            tier_rows = qentry.get("tiers") or {}
+            n_matched = qentry.get("matched_players") or 0
+            # Matched-player-weighted aggregate over the tier rows (the
+            # service ledger conditions on tier; the cell headline wants
+            # the population view).
+            q_sum = sum(r.get("quality_sum") or 0.0
+                        for r in tier_rows.values())
+            p10s = [r.get("quality_p10") for r in tier_rows.values()
+                    if r.get("quality_p10") is not None]
+            w99s = [r.get("wait_p99_s") for r in tier_rows.values()
+                    if r.get("wait_p99_s") is not None]
+            cell["quality"] = {
+                "matched": n_matched,
+                "quality_mean": (round(q_sum / n_matched, 6)
+                                 if n_matched else None),
+                "quality_p10": (round(min(p10s), 6) if p10s else None),
+                "wait_p99_s": (round(max(w99s), 6) if w99s else None),
+            }
+        rt = app.runtime(q)
+        if hasattr(rt.engine, "util_report"):
+            u = rt.engine.util_report()
+            cell["idle_fraction"] = u["idle_fraction"]
+            cell["effective_occupancy"] = u["effective_occupancy"]
+        cell["telemetry"] = app.telemetry.snapshot(
+            limit=trajectory,
+            prefixes=("idle_frac", "slo_good", "slo_total", "pool_size",
+                      "stage_total_p99_ms", "batch_fill", "shed_total",
+                      "expired_total"))
+        tuned = None
+        if app.autotune is not None:
+            cell["autotune"] = {
+                "moves": app.autotune.moves,
+                "failures": app.autotune.failures,
+                "ticks": app.autotune.ticks,
+                "knobs": app.autotune.knobs(),
+                "trace": [list(row)
+                          for row in app.autotune.decision_trace()],
+            }
+            tuned = app.autotune.tuned_config(scenario=scn.name, seed=seed)
+        return cell, tuned
+    finally:
+        await app.stop()
+
+
+def scenario_matrix(matrix: str, *, seed: int = 21,
+                    rate_scale: float = 1.0, time_scale: float = 1.0,
+                    slo_ms: float = 100.0, wait_ms: float = 25.0,
+                    max_waiting: int = 2048, trajectory: int = 120,
+                    no_autotune: bool = False, tuned_dir: str = "") -> dict:
+    """The scenario observatory: run every named scenario (``matrix`` is a
+    comma list, or ``"all"`` for every ``configs/scenarios/*.json``) as
+    one matrix cell — fresh app, seeded population load, autotuner closing
+    the loop — and return one artifact per cell. A cell that fails fails
+    the run.
+
+    ``rate_scale``/``time_scale`` scale every scenario's curve, ``slo_ms``
+    is each cell's SLO target (also the autotuner's steering target),
+    ``wait_ms`` and ``max_waiting`` its static window wait and admission
+    waiting-pool cap, ``trajectory`` the telemetry snapshots kept a cell.
+    ``no_autotune`` runs the static knobs; ``tuned_dir`` writes each
+    cell's converged knobs to ``<dir>/<scenario>.json``."""
+    from matchmaking_tpu.scenario import load_scenario, scenario_names
+
+    names = (scenario_names() if matrix == "all"
+             else [n.strip() for n in matrix.split(",") if n.strip()])
+    cells: list[dict] = []
+    for name in names:
+        log.info("[scenario] cell %s", name)
+        cell, tuned = asyncio.run(_scenario_cell(
+            load_scenario(name), seed=seed, rate_scale=rate_scale,
+            time_scale=time_scale, slo_ms=slo_ms, wait_ms=wait_ms,
+            max_waiting=max_waiting, trajectory=trajectory,
+            no_autotune=no_autotune))
+        if tuned_dir and tuned is not None:
+            os.makedirs(tuned_dir, exist_ok=True)
+            path = os.path.join(tuned_dir, f"{cell['scenario']}.json")
+            with open(path, "w") as f:
+                json.dump(tuned, f, indent=1, sort_keys=True)
+                f.write("\n")
+            cell["tuned_config"] = path
+        log.info("[scenario %s] attainment=%s shed=%s admitted_p99=%s ms "
+                 "autotune_moves=%s", name, cell.get("slo_attainment"),
+                 cell.get("shed"), cell.get("admitted_p99_ms"),
+                 (cell.get("autotune") or {}).get("moves"))
+        cells.append(cell)
+    attainments = [c["slo_attainment"] for c in cells
+                   if c.get("slo_attainment") is not None]
+    return {
+        "metric": (f"scenario-matrix worst-cell SLO attainment "
+                   f"({len(cells)} cells)"),
+        "value": (round(min(attainments), 4) if attainments else None),
+        "unit": "attainment",
+        "vs_baseline": None,
+        "scenario_seed": seed,
+        "scenario_matrix": cells,
+    }
 
 
 async def _run(args) -> dict:

@@ -42,8 +42,7 @@ except ImportError:  # pragma: no cover - aiohttp is in the base image
 
 def build_report(app) -> dict[str, Any]:
     """The full /metrics JSON payload for a MatchmakingApp — module-level so
-    non-HTTP consumers (bench.py snapshots its final report into the BENCH
-    json) share one report shape with the endpoint."""
+    non-HTTP consumers (tests) share one report shape with the endpoint."""
     report = app.metrics.report()
     report["pools"] = {
         name: rt.engine.pool_size()

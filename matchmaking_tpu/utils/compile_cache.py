@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, placed from outside.
 
 Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``'s ``main()``, ``service.app.serve()``) calls
-``enable_compile_cache()`` before its first compile. Tests never do.
+``service.app.serve()``) calls ``enable_compile_cache()`` before its
+first compile. Tests never do.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
 module sets no other directory. Otherwise the cache lives at one fixed path

@@ -17,8 +17,8 @@ Three pieces, all bounded-memory and stdlib-only:
   millisecond still order causally, and ``wall`` stays plain data for
   humans. The DETERMINISTIC subset of the spine (scripted-recovery kinds
   + counter-valued refs, no clocks) is the ``transcript()`` — the
-  bit-identical-across-two-runs artifact ``bench.py --incident-soak``
-  pins, the same determinism bar the crash/failover soaks meet.
+  bit-identical-across-two-runs artifact, the same determinism bar the
+  crash/failover recoveries meet (tests/test_forensics.py).
 - ``IncidentRecorder`` — the black box. A trigger-rule table over spine
   kinds (SLO burn start, breaker trip, failover takeover, crash
   recovery, migration blackout over budget, autotuner oscillation)

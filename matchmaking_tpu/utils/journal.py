@@ -304,8 +304,7 @@ class PoolJournal:
         self.segment_records = 0  # guarded-by: _lock
         self.segment_bytes = 0  # guarded-by: _lock
         #: Lifetime write-amplification accounting: file bytes written vs
-        #: logical payload bytes journaled (bench.py --crash-soak reports
-        #: the ratio).
+        #: logical payload bytes journaled.
         self.bytes_written = 0  # guarded-by: _lock
         self.payload_bytes = 0  # guarded-by: _lock
         self._fd: int | None = None  # guarded-by: _lock
@@ -727,7 +726,7 @@ class PoolJournal:
                 self._fd = None
 
     def abandon(self) -> None:
-        """Crash-fidelity teardown (bench --crash-soak / tests): DROP the
+        """Crash-fidelity teardown (durability tests): DROP the
         uncommitted buffer (a real crash loses it) and close the fd
         without a clean marker or fsync — the on-disk state is exactly
         what a ``kill -9`` would leave."""

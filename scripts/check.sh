@@ -4,8 +4,8 @@
 #      finding outside analysis/baseline.json. Runs FIRST because it is
 #      seconds, not minutes, and a lock-discipline bug should fail fast.
 #   2. tier-1 tests (the ROADMAP.md verify recipe's pytest selection).
-# Lint time is excluded from any bench numbers by construction: bench.py
-# never invokes this script (see BENCH_CONFIGS.md).
+# The benchmark (python3 -m benchmark.run) never invokes this script, so
+# lint time is never in a benchmark number.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -46,8 +46,6 @@ echo "== ingress =="
 # marker: broker burst-callback seam units, the consume-time decode, and
 # the equivalence soaks (consume_batch on vs off, ingress shards 1 vs 4 —
 # identical pairings, normalized responses, and settlement counters).
-# The consume-share regression gate rides the bench-diff section below
-# (e2e_consume_share, direction-aware) whenever MM_BENCH_JSON is set.
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'ingress and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
 
@@ -99,28 +97,6 @@ echo "== durability =="
 # D=2→1 device-loss failover, and the sanitizer's journal twin.
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'durability and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
-# Then a 2-cycle in-process crash-soak smoke through the REAL
-# bench.py --crash-soak path (one run, small load): zero lost, zero dup,
-# and a bounded RTO — the acceptance invariants, seconds-scale.
-python - <<'EOF'
-import json, subprocess, sys
-proc = subprocess.run(
-    [sys.executable, "bench.py", "--crash-soak", "--crash-cycles", "2",
-     "--crash-runs", "1", "--crash-pairs", "3", "--crash-singles", "2",
-     "--crash-overhead-pairs", "60"],
-    capture_output=True, text=True, timeout=600)
-sys.stderr.write(proc.stderr)
-if proc.returncode != 0:
-    sys.exit(f"crash-soak smoke exited {proc.returncode}")
-out = json.loads(proc.stdout.splitlines()[-1])
-print("crash-soak smoke:", json.dumps(out))
-assert out["crash_lost"] == 0, f"lost waiting players: {out['crash_lost']}"
-assert out["crash_dup"] == 0, f"double matches: {out['crash_dup']}"
-assert out["crash_recoveries"] >= 2, out["crash_recoveries"]
-assert out["crash_rto_ms_max"] is not None and \
-    out["crash_rto_ms_max"] < 30_000, f"RTO unbounded: {out['crash_rto_ms_max']}"
-print("crash-soak smoke: OK")
-EOF
 
 echo "== replication =="
 # ISSUE 17 gate: hot-standby journal replication + fenced failover. The
@@ -132,30 +108,6 @@ echo "== replication =="
 # offline journal inspector.
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'replication and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
-# Then a 2-cycle failover-soak smoke through the REAL bench.py
-# --failover-soak path (one run, small load): zero double matches, lost
-# players within the unacked-tail bound at kill time, >= 2 takeovers,
-# and a bounded RTO — the acceptance invariants, seconds-scale.
-python - <<'EOF'
-import json, subprocess, sys
-proc = subprocess.run(
-    [sys.executable, "bench.py", "--failover-soak", "--failover-cycles",
-     "2", "--failover-runs", "1", "--failover-pairs", "3",
-     "--failover-singles", "2"],
-    capture_output=True, text=True, timeout=600)
-sys.stderr.write(proc.stderr)
-if proc.returncode != 0:
-    sys.exit(f"failover-soak smoke exited {proc.returncode}")
-out = json.loads(proc.stdout.splitlines()[-1])
-print("failover-soak smoke:", json.dumps(out))
-assert out["failover_dup"] == 0, f"double matches: {out['failover_dup']}"
-assert out["failover_lost_over_bound"] == 0, \
-    f"lost beyond the unacked-tail bound: {out['failover_lost_over_bound']}"
-assert out["failover_recoveries"] >= 2, out["failover_recoveries"]
-assert out["failover_rto_ms"] is not None and \
-    out["failover_rto_ms"] < 30_000, f"RTO unbounded: {out['failover_rto_ms']}"
-print("failover-soak smoke: OK")
-EOF
 
 echo "== net =="
 # ISSUE 20 gate: real-transport DCN seams. The suite runs by marker
@@ -167,74 +119,6 @@ echo "== net =="
 # sanitizer's ack-beyond-received twin over a real socket.
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'net and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
-# In-proc ≡ socket equivalence pin: the same seeded failover soak over
-# the in-proc link and over real loopback sockets (network nemesis off)
-# must emit BIT-IDENTICAL recovered-state transcripts — the socket
-# transport may change timing, never outcomes.
-python - <<'EOF'
-import json, subprocess, sys
-def run(transport):
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--failover-soak",
-         "--transport", transport, "--failover-cycles", "2",
-         "--failover-runs", "1", "--failover-pairs", "3",
-         "--failover-singles", "2"],
-        capture_output=True, text=True, timeout=600)
-    sys.stderr.write(proc.stderr)
-    if proc.returncode != 0:
-        sys.exit(f"failover-soak ({transport}) exited {proc.returncode}")
-    return json.loads(proc.stdout.splitlines()[-1])
-inproc, loop = run("inproc"), run("socket-loopback")
-print("equivalence pin digest:", inproc["failover_transcript_digest"])
-assert inproc["failover_transcript_digest"] == \
-    loop["failover_transcript_digest"], (
-    f"in-proc != socket-loopback transcript: "
-    f"{inproc['failover_transcript_digest']} vs "
-    f"{loop['failover_transcript_digest']}")
-print("equivalence pin: OK")
-EOF
-# Then the CROSS-PROCESS socket failover smoke through the REAL
-# bench.py --failover-soak --transport=socket path: primary / standby /
-# lease-service as separate OS processes over UDS, SIGKILL mid-load
-# under scripted nemesis (drop + dup + delay + a mid-stream connection
-# reset + an asymmetric ack/lease partition on the last cycle). Gates:
-# zero double matches, losses within the unacked-tail bound, both fence
-# seams refuse at the fenced ex-primary, zero heartbeat false
-# positives, bounded RTO, and a bit-identical transcript across two
-# seeded runs.
-python - <<'EOF'
-import json, subprocess, sys
-proc = subprocess.run(
-    [sys.executable, "bench.py", "--failover-soak", "--transport",
-     "socket", "--failover-cycles", "2", "--failover-runs", "2",
-     "--failover-pairs", "3", "--failover-singles", "2"],
-    capture_output=True, text=True, timeout=600)
-sys.stderr.write(proc.stderr)
-if proc.returncode != 0:
-    sys.exit(f"socket failover smoke exited {proc.returncode}")
-out = json.loads(proc.stdout.splitlines()[-1])
-print("socket failover smoke:", json.dumps(out))
-assert out["socket_failover_dup"] == 0, \
-    f"double matches over sockets: {out['socket_failover_dup']}"
-assert out["socket_failover_lost_over_bound"] == 0, \
-    f"lost beyond the unacked-tail bound: " \
-    f"{out['socket_failover_lost_over_bound']}"
-assert out["socket_failover_recoveries"] >= 2, \
-    out["socket_failover_recoveries"]
-assert out["socket_fenced_probe_failures"] == 0, \
-    f"a fence seam leaked at the ex-primary: " \
-    f"{out['socket_fenced_probe_failures']}"
-assert out["heartbeat_false_positive_count"] == 0, \
-    f"liveness false positives on a healthy link: " \
-    f"{out['heartbeat_false_positive_count']}"
-assert out["socket_link_reconnects"] >= 1, "scripted reset never healed"
-assert out["socket_failover_rto_ms"] is not None and \
-    out["socket_failover_rto_ms"] < 30_000, \
-    f"RTO unbounded: {out['socket_failover_rto_ms']}"
-assert out["socket_failover_transcript_identical"], \
-    "two seeded cross-process runs diverged"
-print("socket failover smoke: OK")
-EOF
 
 echo "== protocol =="
 # ISSUE 19 gate: protocol conformance. The suite runs by marker first —
@@ -247,21 +131,16 @@ echo "== protocol =="
 # seeded mutant's minimized digest-replayable counterexample).
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'protocol and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
-# Then the committed-scope smoke through the REAL bench.py --modelcheck
-# path: 2 queues x depth 6 x {expire,crash,drop,dup} x fault budget 2
-# must be EXHAUSTIVE with zero violations (~22 s on /dev/shm, ~30k
-# unique states), and the seeded-mutant gate must catch all four
-# protocol mutants with replay-verified counterexamples while the
-# unmutated baseline stays clean. Pure host-side (no jax backend).
-python - <<'EOF'
-import json, subprocess, sys
-proc = subprocess.run(
-    [sys.executable, "bench.py", "--modelcheck"],
-    capture_output=True, text=True, timeout=300)
-sys.stderr.write(proc.stderr)
-if proc.returncode != 0:
-    sys.exit(f"modelcheck smoke exited {proc.returncode}")
-out = json.loads(proc.stdout.splitlines()[-1])
+# Then the committed scope: 2 queues x depth 6 x {expire,crash,drop,dup}
+# x fault budget 2 must be EXHAUSTIVE with zero violations, and the
+# seeded-mutant gate must catch all four protocol mutants with
+# replay-verified counterexamples while the unmutated baseline stays
+# clean. Pure host-side (no jax backend).
+JAX_PLATFORMS=cpu python - <<'EOF'
+import json
+from matchmaking_tpu.analysis.modelcheck import (
+    run_modelcheck, run_mutation_gate)
+out = run_modelcheck()
 print("modelcheck smoke:", json.dumps(
     {k: out[k] for k in ("modelcheck_states_explored", "modelcheck_nodes",
                          "modelcheck_exhaustive", "modelcheck_violations",
@@ -270,13 +149,7 @@ assert out["modelcheck_violations"] == 0, \
     f"protocol violation: {out['modelcheck_violation']}\n" \
     + "\n".join(out["modelcheck_timeline"])
 assert out["modelcheck_exhaustive"], "scope not exhausted (cap hit)"
-proc = subprocess.run(
-    [sys.executable, "bench.py", "--modelcheck-mutations"],
-    capture_output=True, text=True, timeout=300)
-sys.stderr.write(proc.stderr)
-if proc.returncode != 0:
-    sys.exit(f"mutation gate exited {proc.returncode}")
-gate = json.loads(proc.stdout.splitlines()[-1])
+gate = run_mutation_gate()
 for name, rec in sorted(gate["mutation_gate_mutants"].items()):
     print(f"mutant {name}: caught={rec['caught']} "
           f"replay_ok={rec['replay_ok']} steps={rec['steps']} "
@@ -343,38 +216,11 @@ echo "== scenario observatory =="
 # telemetry counter-reset hardening, and the closed-loop autotuner
 # acceptance (autotune-on beats static on a scripted overload, with a
 # bit-identical knob-decision audit trace across two runs). The suite
-# includes the seeded 2-cell mini-matrix smoke driving the REAL
-# bench.py --scenario-matrix path in-process: artifact schema, autotuner
-# audit ring non-empty, and replay identity of the scenario digests.
+# includes the seeded 2-cell mini-matrix smoke through
+# loadgen.scenario_matrix in-process: artifact schema, autotuner audit
+# ring non-empty, and replay identity of the scenario digests.
 JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'scenario and not slow' \
     --continue-on-collection-errors -p no:cacheprovider
-
-echo "== bench diff =="
-# Trajectory gate (ISSUE 8 satellite): when a fresh BENCH json is supplied
-# (MM_BENCH_JSON=/path scripts/check.sh), compare it against the newest
-# committed BENCH_r*.json and fail on >10% regression in throughput / p99
-# / quality / disparity. Skipped when no fresh run is on hand — check.sh
-# must stay a seconds-scale gate, not a bench run.
-if [ -n "${MM_BENCH_JSON:-}" ]; then
-    python scripts/bench_diff.py "$MM_BENCH_JSON"
-else
-    echo "(skipped: set MM_BENCH_JSON=<fresh BENCH json> to gate)"
-fi
-# Scenario-matrix gate (ISSUE 13): a fresh `bench.py --scenario-matrix`
-# artifact diffs against the newest committed SCENARIOS_r*.json —
-# per-cell slo_attainment/quality up, admitted_p99/expired down, aborted
-# cells skipped.
-if [ -n "${MM_SCENARIO_JSON:-}" ]; then
-    scenario_base=$(ls SCENARIOS_r*.json 2>/dev/null | sort | tail -1)
-    if [ -n "$scenario_base" ]; then
-        python scripts/bench_diff.py "$MM_SCENARIO_JSON" \
-            --baseline "$scenario_base"
-    else
-        echo "(no committed SCENARIOS_r*.json baseline yet)"
-    fi
-else
-    echo "(skipped: set MM_SCENARIO_JSON=<fresh scenario-matrix json> to gate)"
-fi
 
 echo "== tier-1 =="
 exec env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \

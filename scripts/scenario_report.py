@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Pretty-print a scenario-matrix artifact (ISSUE 13).
 
-Reads the JSON ``bench.py --scenario-matrix`` emits (raw, JSON-lines, or a
-driver artifact wrapping it under ``"parsed"`` — same shapes bench_diff
-accepts) and renders the capacity-planning story:
+Reads the result of ``loadgen.scenario_matrix`` saved as JSON (raw,
+JSON-lines, or an artifact wrapping it under ``"parsed"``) and renders the
+capacity-planning story:
 
 - the matrix summary table — one row per cell: offered/matched/shed/
   expired, SLO attainment, admitted p99, autotuner move count;
@@ -80,7 +80,7 @@ def render_matrix(doc: dict, out=sys.stdout) -> None:
     cells = doc.get("scenario_matrix") or []
     if not cells:
         print("no scenario_matrix rows in this artifact "
-              "(run bench.py --scenario-matrix)", file=out)
+              "(run loadgen.scenario_matrix)", file=out)
         return
     print(f"scenario matrix (seed {doc.get('scenario_seed')}, worst-cell "
           f"attainment {doc.get('value')}):", file=out)
@@ -164,7 +164,7 @@ def render(doc: dict, cell_name: str = "", full: bool = False,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("artifact", help="scenario-matrix JSON "
-                                     "(bench.py --scenario-matrix output)")
+                                     "(loadgen.scenario_matrix's result)")
     ap.add_argument("--cell", default="",
                     help="render one cell's full story (trajectory "
                          "sparklines, attribution, autotune ladder)")

@@ -25,10 +25,11 @@ request slow" workflow:
     # per-queue attribution summary (/debug/attribution)
     python scripts/trace_dump.py --attribution
 
-    # match-quality & fairness summary (/debug/quality, ISSUE 8) — live,
-    # or offline from a BENCH json's e2e_frontier rows
+    # match-quality & fairness summary (/debug/quality)
     python scripts/trace_dump.py --quality
-    python scripts/trace_dump.py --quality --bench-json <bench.json>
+
+    # a scenario-matrix artifact (loadgen.scenario_matrix's result as JSON)
+    python scripts/trace_dump.py --scenario --bench-json <matrix.json>
 
 Stdlib (urllib) transport — usable inside the service container; the
 ``--gaps`` classifier imports matchmaking_tpu.service.attribution, which is
@@ -190,33 +191,6 @@ def render_quality(body: dict, out=sys.stdout) -> None:
         print("", file=out)
 
 
-def render_frontier(doc: dict, out=sys.stdout) -> None:
-    """The quality-vs-latency frontier from a BENCH json (e2e_frontier
-    rows, ISSUE 8)."""
-    if isinstance(doc.get("parsed"), dict):
-        doc = doc["parsed"]
-    rows = doc.get("e2e_frontier", [])
-    if not rows:
-        print("no e2e_frontier rows in this BENCH json "
-              "(run bench.py --e2e-quality)", file=out)
-        return
-    print("quality-vs-latency frontier (stricter threshold -> closer "
-          "matches, longer waits):", file=out)
-    print(f"  {'thr':>6} {'matched':>8} {'q_mean':>8} {'q_p10':>8} "
-          f"{'spread':>8} {'waitp50ms':>10} {'waitp99ms':>10} "
-          f"{'disparity':>10}", file=out)
-    for r in sorted(rows, key=lambda r: r.get("threshold", 0.0)):
-        print(f"  {r.get('threshold', 0):>6g} {r.get('matched', 0):>8} "
-              f"{r.get('quality_mean')!s:>8} {r.get('quality_p10')!s:>8} "
-              f"{r.get('spread_mean')!s:>8} "
-              f"{r.get('wait_at_match_ms_p50')!s:>10} "
-              f"{r.get('wait_at_match_ms_p99')!s:>10} "
-              f"{r.get('quality_disparity')!s:>10}", file=out)
-    for key in ("e2e_frontier_spread_monotone", "e2e_frontier_wait_monotone"):
-        if key in doc:
-            print(f"  {key}: {doc[key]}", file=out)
-
-
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--host", default="127.0.0.1")
@@ -241,8 +215,7 @@ def main(argv=None) -> None:
                          "(/debug/attribution)")
     ap.add_argument("--quality", action="store_true",
                     help="match-quality & fairness summary "
-                         "(/debug/quality; with --bench-json, the "
-                         "e2e_frontier rows of a BENCH artifact)")
+                         "(/debug/quality)")
     ap.add_argument("--scenario", action="store_true",
                     help="scenario-matrix artifact summary (ISSUE 13): "
                          "with --bench-json, the matrix table + per-cell "
@@ -253,8 +226,8 @@ def main(argv=None) -> None:
                     help="with --scenario --bench-json: one cell's full "
                          "story")
     ap.add_argument("--bench-json", default="",
-                    help="read a BENCH json instead of a live service "
-                         "(with --quality or --scenario)")
+                    help="with --scenario: read a scenario-matrix "
+                         "artifact instead of a live service")
     ap.add_argument("--json", action="store_true",
                     help="raw JSON instead of the waterfall rendering")
     args = ap.parse_args(argv)
@@ -287,14 +260,6 @@ def main(argv=None) -> None:
         return
 
     if args.quality:
-        if args.bench_json:
-            with open(args.bench_json, encoding="utf-8") as f:
-                doc = json.load(f)
-            if args.json:
-                print(json.dumps(doc.get("e2e_frontier", []), indent=2))
-            else:
-                render_frontier(doc)
-            return
         body = _get(base, "/debug/quality", {"queue": args.queue})
         if args.json:
             print(json.dumps(body, indent=2))

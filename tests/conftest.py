@@ -2,8 +2,8 @@
 
 Forces JAX onto the host CPU backend with 8 virtual devices BEFORE jax is
 imported anywhere, so sharding/collective code paths (mesh axis ``pool``) are
-exercised without TPU hardware (SURVEY.md §4 "For the rebuild"). Bench runs
-(bench.py) use the real TPU; tests use this virtual mesh.
+exercised without TPU hardware (SURVEY.md §4 "For the rebuild"). The
+benchmark and chip_smoke.py use the real TPU; tests use this virtual mesh.
 """
 
 import os

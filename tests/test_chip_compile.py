@@ -23,8 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from matchmaking_tpu.core.pool import PACKED_ROWS, PlayerPool
 
-#: BASELINE config #1's geometry (configs/elo_1v1.json) and bench.py's
-#: default window.
+#: BASELINE config #1's geometry (configs/elo_1v1.json) and a 4,096-request
+#: window.
 CAPACITY = 131_072
 POOL_BLOCK = 8192
 WINDOW = 4096

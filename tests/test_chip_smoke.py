@@ -2,8 +2,7 @@
 
 The script itself needs the chip (the driver runs it there); what runs here
 is everything it checks with, over the same app/engine entry points, plus
-the contract that with no TPU it — and bench.py — exit non-zero and print
-no result.
+the contract that with no TPU it exits non-zero and prints no result.
 """
 
 import asyncio
@@ -76,7 +75,7 @@ def test_sharded_pool_matches_single_device_at_toy_size():
 
 
 @pytest.mark.parametrize("argv", [
-    ["chip_smoke.py"], ["chip_smoke.py", "--chips", "4"], ["bench.py"]])
+    ["chip_smoke.py"], ["chip_smoke.py", "--chips", "4"]])
 def test_no_chip_means_nonzero_exit_and_no_result(argv):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, *argv], cwd=REPO, env=env,

@@ -306,48 +306,95 @@ async def _run_crash_cycle(jdir, *, chaos=None):
     return waiting, matches
 
 
-async def test_crash_recovery_service_roundtrip(tmp_path):
+def _cycle_load(cycle: int) -> "tuple[tuple[str, float], ...]":
+    """Fresh load for a successor's cycle: two pairs that match and one
+    far single that waits, apart from every earlier cycle's ratings."""
+    base = 2500.0 + 1000.0 * cycle
+    return ((f"c{cycle}p0", base), (f"c{cycle}p1", base + 1.0),
+            (f"c{cycle}p2", base + 200.0), (f"c{cycle}p3", base + 201.0),
+            (f"c{cycle}s0", 40_000.0 + 1000.0 * cycle))
+
+
+@pytest.mark.parametrize("cycles", [1, 2])
+async def test_crash_recovery_service_roundtrip(tmp_path, cycles):
+    """``cycles`` hard crashes on one journal directory, each followed by a
+    successor boot that recovers it. Every recovery: zero lost waiting
+    players, the dedup cache restored, RTO measured; across them all, no
+    player matched twice."""
     from matchmaking_tpu.service.app import MatchmakingApp
 
     jdir = tmp_path / "j"
     pre_waiting, matches = await _run_crash_cycle(jdir)
     assert pre_waiting == {"s0"}
     assert set(matches) == {"p0", "p1", "p2", "p3"}
+    published = [("p0", 1500.0), ("p1", 1501.0), ("p2", 2000.0),
+                 ("p3", 2001.0), ("s0", 4000.0)]
+    #: Every match id each player was ever answered with, over all boots.
+    answered: dict[str, set] = {pid: {mid} for pid, mid in matches.items()}
 
-    # Successor boot: recovery replays snapshot + journal tail — zero
-    # lost waiting players, the dedup cache restored, RTO measured.
-    app2 = MatchmakingApp(durable_cfg(jdir))
-    await app2.start()
-    rt2 = app2.runtime(Q)
-    try:
-        assert {r.id for r in rt2.engine.waiting()} == pre_waiting
-        assert app2.metrics.counters.get("crash_recoveries") == 1
-        rto = app2.metrics.gauges.get(f"crash_rto_ms[{Q}]")
-        assert rto is not None and rto > 0.0
-        rec = rt2.last_recovery
-        assert rec is not None and rec["transcript"]["waiting"] == ["s0"]
-        assert not rec["fallback"]
-        assert any(e["kind"] == "crash_recovered"
-                   for e in app2.events.snapshot())
+    for cycle in range(1, cycles + 1):
+        # Successor boot: recovery replays snapshot + journal tail — zero
+        # lost waiting players, the dedup cache restored, RTO measured.
+        app2 = MatchmakingApp(durable_cfg(jdir))
+        await app2.start()
+        rt2 = app2.runtime(Q)
+        crashed = False
+        try:
+            assert {r.id for r in rt2.engine.waiting()} == pre_waiting
+            assert app2.metrics.counters.get("crash_recoveries") == 1
+            rto = app2.metrics.gauges.get(f"crash_rto_ms[{Q}]")
+            assert rto is not None and rto > 0.0
+            rec = rt2.last_recovery
+            assert rec is not None
+            assert rec["transcript"]["waiting"] == sorted(pre_waiting)
+            assert not rec["fallback"]
+            assert any(e["kind"] == "crash_recovered"
+                       for e in app2.events.snapshot())
 
-        # At-least-once reconciliation: the broker redelivers EVERY
-        # pre-crash request. Matched players must replay the SAME match
-        # (zero double matches), the waiting player re-enters as a
-        # duplicate-enqueue no-op (zero duplicate pool entries).
-        replays: list[dict] = []
-        _collect_responses(app2, "dur.replays", replays)
-        for pid, rating in (("p0", 1500.0), ("p1", 1501.0),
-                            ("p2", 2000.0), ("p3", 2001.0),
-                            ("s0", 4000.0)):
-            _publish(app2, pid, rating, "dur.replays")
-        assert await settle(app2, rt2, Q, reply_queues=("dur.replays",))
-        replayed = {r["player_id"]: r["match"]["match_id"]
-                    for r in replays if r.get("status") == "matched"}
-        assert replayed == matches  # byte-for-byte the cached truth
-        assert {r.id for r in rt2.engine.waiting()} == {"s0"}
-        assert app2.metrics.counters.get("deduped_replays") >= 4
-    finally:
-        await app2.stop()
+            # At-least-once reconciliation: the broker redelivers EVERY
+            # pre-crash request. Matched players must replay the SAME
+            # match (zero double matches), the waiting players re-enter
+            # as duplicate-enqueue no-ops (zero duplicate pool entries).
+            replies: list[dict] = []
+            reply_q = f"dur.replays{cycle}"
+            _collect_responses(app2, reply_q, replies)
+            for pid, rating in published:
+                _publish(app2, pid, rating, reply_q)
+            assert await settle(app2, rt2, Q, reply_queues=(reply_q,))
+            replayed = {r["player_id"]: r["match"]["match_id"]
+                        for r in replies if r.get("status") == "matched"}
+            assert replayed == matches  # byte-for-byte the cached truth
+            assert {r.id for r in rt2.engine.waiting()} == pre_waiting
+            assert app2.metrics.counters.get("deduped_replays") >= len(
+                matches)
+            if cycle == cycles:
+                continue
+
+            # Fresh load on the successor, then its own hard crash.
+            load = _cycle_load(cycle)
+            for pid, rating in load:
+                _publish(app2, pid, rating, reply_q)
+            assert await settle(app2, rt2, Q, matched_at_least=4,
+                                reply_queues=(reply_q,))
+            for r in replies:
+                if r.get("status") == "matched":
+                    answered.setdefault(r["player_id"], set()).add(
+                        r["match"]["match_id"])
+            fresh = {pid for pid, _ in load if pid in answered}
+            assert fresh == {f"c{cycle}p{i}" for i in range(4)}
+            matches.update({pid: next(iter(answered[pid]))
+                            for pid in fresh})
+            pre_waiting = pre_waiting | {f"c{cycle}s0"}
+            assert {r.id for r in rt2.engine.waiting()} == pre_waiting
+            published.extend(load)
+            await app2.crash()
+            crashed = True
+        finally:
+            if not crashed:
+                await app2.stop()
+
+    # No player was ever answered with two different matches.
+    assert all(len(ids) == 1 for ids in answered.values()), answered
 
 
 async def test_clean_shutdown_skips_recovery(tmp_path):

@@ -4,7 +4,7 @@ clean exhaustive runs on the real objects, every seeded mutant caught
 with a minimized digest-replayable counterexample.
 
 The fast scopes here are tier-1; the committed CI smoke scope (depth 6)
-runs in scripts/check.sh via ``bench.py --modelcheck``.
+runs in scripts/check.sh through ``analysis.modelcheck.run_modelcheck``.
 """
 
 import dataclasses

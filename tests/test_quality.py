@@ -569,7 +569,7 @@ def test_quality_counters_replay_stable_across_chaos_runs(sanitizer):
 
 
 # ---------------------------------------------------------------------------
-# loadgen + bench_diff satellites
+# loadgen satellite
 
 
 @pytest.mark.asyncio
@@ -601,56 +601,6 @@ async def test_loadgen_quality_accounting(sanitizer):
         assert qs["wait_gap_ms_mean"] >= 0.0
     finally:
         await app.stop()
-
-
-def test_bench_diff_detects_regressions(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(os.path.dirname(__file__), "..",
-                                   "scripts", "bench_diff.py"))
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-
-    base = {"value": 1000.0, "e2e_p99_ms": 100.0,
-            "e2e_frontier": [{"threshold": 50.0, "quality_mean": 0.8,
-                              "wait_at_match_ms_p99": 200.0,
-                              "quality_disparity": 0.1}]}
-    same = json.loads(json.dumps(base))
-    rows = bd.diff(base, same, threshold=0.10)
-    assert rows and not any(r["regressed"] for r in rows)
-    # throughput down 20% → regression; p99 down (improvement) → not
-    worse = dict(base, value=800.0, e2e_p99_ms=50.0)
-    rows = bd.diff(base, worse, threshold=0.10)
-    flagged = {r["metric"] for r in rows if r["regressed"]}
-    assert flagged == {"value"}
-    # frontier quality regression caught by threshold-matched row
-    worse_f = json.loads(json.dumps(base))
-    worse_f["e2e_frontier"][0]["quality_mean"] = 0.6
-    rows = bd.diff(base, worse_f, threshold=0.10)
-    assert any(r["regressed"] and "quality_mean" in r["metric"]
-               for r in rows)
-    # zero-baseline disparity (a perfectly fair committed round) must
-    # still gate an absolute worsening — skipping would disable the
-    # fairness gate from a clean baseline.
-    fair = json.loads(json.dumps(base))
-    fair["e2e_frontier"][0]["quality_disparity"] = 0.0
-    unfair = json.loads(json.dumps(fair))
-    unfair["e2e_frontier"][0]["quality_disparity"] = 0.5
-    rows = bd.diff(fair, unfair, threshold=0.10)
-    assert any(r["regressed"] and "quality_disparity" in r["metric"]
-               for r in rows)
-    assert not any(r["regressed"]
-                   for r in bd.diff(fair, json.loads(json.dumps(fair)),
-                                    threshold=0.10))
-    # missing metrics on either side are skipped, not failed
-    rows = bd.diff({"value": 10.0}, {"e2e_p99_ms": 5.0}, threshold=0.1)
-    assert rows == []
-    # file loading: driver artifact shape ({"parsed": {...}})
-    p = tmp_path / "wrapped.json"
-    p.write_text(json.dumps({"parsed": base, "tail": "..."}))
-    assert bd.load_result(str(p))["value"] == 1000.0
 
 
 def test_waited_ms_wire_roundtrip():

@@ -11,7 +11,7 @@
 - Client retry-on-shed: flagged cohort members re-publish once after a
   shed, accounted per cohort.
 - The 2-cell seeded mini-matrix smoke (scripts/check.sh runs this suite
-  by marker): the REAL ``bench.py --scenario-matrix`` path in-process —
+  by marker): ``loadgen.scenario_matrix`` in-process —
   artifact schema, autotuner audit ring non-empty on the overloaded cell,
   a failed cell failing the run, and replay identity of the scenario digests
   across two matrix runs.
@@ -19,8 +19,6 @@
 
 import asyncio
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -42,11 +40,9 @@ from matchmaking_tpu.scenario import (
     scenario_names,
 )
 from matchmaking_tpu.service.app import MatchmakingApp
-from matchmaking_tpu.service.loadgen import offered_load
+from matchmaking_tpu.service.loadgen import offered_load, scenario_matrix
 
 pytestmark = pytest.mark.scenario
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EXPECTED_SCENARIOS = {"steady", "diurnal", "flash-crowd", "skewed-ladder",
                       "retry-storm", "mixed-tier-peak"}
@@ -270,26 +266,23 @@ async def test_scenario_mode_rejects_conflicting_models():
 # ---- the mini-matrix smoke (check.sh section) ------------------------------
 
 def _matrix_args(**over):
-    import argparse
-
-    ns = argparse.Namespace(
-        scenario_matrix="steady,flash-crowd",
-        scenario_seed=21,
-        scenario_rate_scale=0.6,
-        scenario_time_scale=0.4,
+    kw = dict(
+        matrix="steady,flash-crowd",
+        seed=21,
+        rate_scale=0.6,
+        time_scale=0.4,
         # The SLO target (also the tuner's p99 target) sits below the
         # cells' own 25 ms window wait, so the flash crowd runs hot on a
         # host of any speed and the tuner has a reason to move.
-        scenario_slo_ms=20.0,
-        scenario_wait_ms=25.0,
-        scenario_max_waiting=2048,
-        scenario_trajectory=60,
-        scenario_no_autotune=False,
-        scenario_tuned_dir="",
+        slo_ms=20.0,
+        wait_ms=25.0,
+        max_waiting=2048,
+        trajectory=60,
+        no_autotune=False,
+        tuned_dir="",
     )
-    for k, v in over.items():
-        setattr(ns, k, v)
-    return ns
+    kw.update(over)
+    return kw
 
 
 _CELL_SCHEMA_KEYS = {
@@ -300,20 +293,14 @@ _CELL_SCHEMA_KEYS = {
 
 
 def test_mini_matrix_smoke_schema_audit_and_replay_identity(tmp_path):
-    """The check.sh gate: a seeded 2-cell matrix through the REAL
-    bench.py --scenario-matrix path, twice. Asserts the trajectory
+    """The check.sh gate: a seeded 2-cell matrix through
+    ``loadgen.scenario_matrix``, twice. Asserts the trajectory
     artifact schema, a non-empty autotuner audit ring on the overloaded
     cell, a written tuned-config artifact, and replay identity — the
     seeded scenario digests (the full arrival+incident transcript) must
     agree bit for bit across the two runs."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    out1 = bench.bench_scenario_matrix(
-        _matrix_args(scenario_tuned_dir=str(tmp_path)))
-    out2 = bench.bench_scenario_matrix(_matrix_args())
+    out1 = scenario_matrix(**_matrix_args(tuned_dir=str(tmp_path)))
+    out2 = scenario_matrix(**_matrix_args())
     for out in (out1, out2):
         cells = out["scenario_matrix"]
         assert [c["scenario"] for c in cells] == ["steady", "flash-crowd"]
@@ -347,52 +334,6 @@ def test_mini_matrix_smoke_schema_audit_and_replay_identity(tmp_path):
 def test_matrix_failed_cell_fails_the_run():
     """A broken cell (an unknown scenario here) fails the whole matrix:
     no cell is recorded as aborted and no result comes back."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
     with pytest.raises(FileNotFoundError, match="no-such-scenario"):
-        bench.bench_scenario_matrix(
-            _matrix_args(scenario_matrix="no-such-scenario,steady",
-                         scenario_time_scale=0.25))
-
-
-def test_bench_diff_gates_scenario_cells_and_skips_aborted():
-    """bench_diff matches cells by scenario name, gates direction-aware
-    (attainment/quality up, admitted p99/expired down), and skips
-    aborted cells on either side."""
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import bench_diff as bd
-    finally:
-        sys.path.pop(0)
-    base = {"scenario_matrix": [
-        {"scenario": "flash-crowd", "slo_attainment": 0.98,
-         "admitted_p99_ms": 50.0, "expired": 0,
-         "quality": {"quality_mean": 0.9, "quality_p10": 0.7}},
-        {"scenario": "diurnal", "slo_attainment": 0.99,
-         "admitted_p99_ms": 40.0, "expired": 0},
-    ]}
-    worse = {"scenario_matrix": [
-        {"scenario": "flash-crowd", "slo_attainment": 0.80,
-         "admitted_p99_ms": 70.0, "expired": 5,
-         "quality": {"quality_mean": 0.7, "quality_p10": 0.7}},
-        {"scenario": "diurnal", "abort_reason": "backend_unavailable"},
-    ]}
-    flags = {r["metric"]: r["regressed"]
-             for r in bd.diff(base, worse, threshold=0.10)}
-    assert flags["scenario[flash-crowd].slo_attainment"] is True
-    assert flags["scenario[flash-crowd].admitted_p99_ms"] is True
-    assert flags["scenario[flash-crowd].expired"] is True
-    assert flags["scenario[flash-crowd].quality.quality_mean"] is True
-    assert flags["scenario[flash-crowd].quality.quality_p10"] is False
-    # The aborted diurnal cell contributed NO rows.
-    assert not any("diurnal" in m for m in flags)
-    better = {"scenario_matrix": [
-        {"scenario": "flash-crowd", "slo_attainment": 1.0,
-         "admitted_p99_ms": 20.0, "expired": 0,
-         "quality": {"quality_mean": 0.95, "quality_p10": 0.8}},
-    ]}
-    assert not any(r["regressed"]
-                   for r in bd.diff(base, better, threshold=0.10))
+        scenario_matrix(**_matrix_args(matrix="no-such-scenario,steady",
+                                       time_scale=0.25))
