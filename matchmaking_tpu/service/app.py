@@ -127,7 +127,8 @@ class _QueueRuntime:
             # near-deadline tier-0 request dispatches in the next device
             # window. The key is a pure function of the delivery's cached
             # tier + stamped header — no clock reads (determinism rule).
-            sort_key=self._edf_key if app.cfg.overload.edf else None)
+            sort_key=self._edf_key if app.cfg.overload.edf else None,
+            counters=app.metrics.counters)
         #: Live in-flight window cap (the backpressure gate in
         #: _dispatch_pipelined), initialized from the frozen engine
         #: config. The online autotuner (control/autotune.py, ISSUE 13)

@@ -9,6 +9,13 @@ until the previous one's flush callback returns — which is the atomicity
 guarantee (a matched player is out of the pool before anyone else can see
 them; SURVEY.md §7 "Hard parts").
 
+A window's deadline is the submit time of the oldest request still pending
+plus ``max_wait_ms``, on the monotonic clock: a request is held at most
+``max_wait_ms``, or until the flush that is running when its deadline
+falls has returned — then the next window is cut at once. The deadline
+does not restart when a flush returns, so a slow flush does not add a
+fresh ``max_wait_ms`` to the requests that arrived during it.
+
 Concurrency contract: all state (``_pending``/``_submitted``/the events)
 is event-loop-confined — ``submit()`` must be called from the loop, never
 from a worker thread (use ``loop.call_soon_threadsafe`` to cross). There
@@ -24,15 +31,24 @@ import time
 from typing import Awaitable, Callable, Generic, TypeVar
 
 from matchmaking_tpu.config import BatcherConfig
+from matchmaking_tpu.utils.metrics import Counter
 
 T = TypeVar("T")
+
+#: What closed a window, each counted as ``batcher_cuts_<trigger>``:
+#: ``size`` — the size event (a full window, or ``flush_hint``/``close``);
+#: ``deadline`` — the oldest request's deadline fell while waiting;
+#: ``overdue`` — the deadline had already passed when the previous flush
+#: returned, so the window was cut with no wait.
+CUT_TRIGGERS = ("size", "deadline", "overdue")
 
 
 class Batcher(Generic[T]):
     def __init__(self, cfg: BatcherConfig,
                  flush: Callable[[list[T]], Awaitable[None]],
                  observe_window: Callable[[int, float], None] | None = None,
-                 sort_key: "Callable[[T], object] | None" = None):
+                 sort_key: "Callable[[T], object] | None" = None,
+                 counters: Counter | None = None):
         self.cfg = cfg
         self._flush = flush
         #: Earliest-deadline-first window cutting (OverloadConfig.edf): when
@@ -63,11 +79,18 @@ class Batcher(Generic[T]):
         #: rest of the batcher state.
         self.max_batch = cfg.max_batch
         self.max_wait_ms = cfg.max_wait_ms
+        #: Cuts by trigger (``CUT_TRIGGERS``), counted into the app's
+        #: counters; created at 0 so an operator sees all three at once.
+        self._counters = counters
+        if counters is not None:
+            for trigger in CUT_TRIGGERS:
+                counters.inc(f"batcher_cuts_{trigger}", 0.0)
         self._pending: list[T] = []
-        #: Per-item submit times, parallel to _pending — the cut reports
-        #: the OLDEST remaining item's true wait, so carried-over backlog
-        #: (items sliced into a later window under saturation) is not
-        #: under-reported exactly when queueing is the p99 cause.
+        #: Per-item submit times, parallel to _pending. The oldest sets the
+        #: next window's deadline, and the cut reports the OLDEST remaining
+        #: item's true wait, so carried-over backlog (items sliced into a
+        #: later window under saturation) is not under-reported exactly
+        #: when queueing is the p99 cause.
         self._submitted: list[float] = []
         self._first = asyncio.Event()   # first item of a window arrived
         self._full = asyncio.Event()    # size trigger
@@ -78,8 +101,7 @@ class Batcher(Generic[T]):
         if self._closed:
             raise RuntimeError("batcher closed")
         self._pending.append(item)
-        if self._observe is not None:
-            self._submitted.append(time.monotonic())
+        self._submitted.append(time.monotonic())
         self._first.set()
         if len(self._pending) >= self.max_batch:
             self._full.set()
@@ -95,9 +117,7 @@ class Batcher(Generic[T]):
         if not items:
             return
         self._pending.extend(items)
-        if self._observe is not None:
-            now = time.monotonic()
-            self._submitted.extend([now] * len(items))
+        self._submitted.extend([time.monotonic()] * len(items))
         self._first.set()
         if len(self._pending) >= self.max_batch:
             self._full.set()
@@ -113,8 +133,7 @@ class Batcher(Generic[T]):
             order = sorted(range(len(self._pending)),
                            key=lambda i: key(self._pending[i]))
             self._pending = [self._pending[i] for i in order]
-            if self._observe is not None:
-                self._submitted = [self._submitted[i] for i in order]
+            self._submitted = [self._submitted[i] for i in order]
         window = self._pending[: self.max_batch]
         self._pending = self._pending[self.max_batch:]
         if self._observe is not None and window:
@@ -125,14 +144,12 @@ class Batcher(Generic[T]):
             # under-report batcher wait precisely while EDF is starving
             # someone — the signal the adaptive limiter feeds on.
             age = time.monotonic() - min(self._submitted)
-            self._submitted = self._submitted[len(window):]
             self._observe(len(window), max(0.0, age))
+        self._submitted = self._submitted[len(window):]
         return window
 
     async def _run(self) -> None:
         while not self._closed:
-            # Re-read per window: the autotuner may retune the wait knob.
-            max_wait = self.max_wait_ms / 1000.0
             if not self._pending:
                 # Idle: wake immediately on the window's first item.
                 self._first.clear()
@@ -142,16 +159,27 @@ class Batcher(Generic[T]):
                     continue
                 if not self._pending:
                     continue
-            # Window open: close after max_wait unless the size trigger
-            # fires first.
+            # Window open: close at the oldest pending request's deadline
+            # unless the size trigger fires first. Re-read per window: the
+            # autotuner may retune the wait knob. Under EDF the minimum
+            # spans the carried-over remainder too, as _cut's age does.
             self._full.clear()
+            trigger = "size"
             if len(self._pending) < self.max_batch:
-                try:
-                    await asyncio.wait_for(self._full.wait(), timeout=max_wait)
-                except asyncio.TimeoutError:
-                    pass
+                deadline = min(self._submitted) + self.max_wait_ms / 1000.0
+                delay = deadline - time.monotonic()
+                if delay <= 0.0:
+                    trigger = "overdue"
+                else:
+                    try:
+                        await asyncio.wait_for(self._full.wait(),
+                                               timeout=delay)
+                    except asyncio.TimeoutError:
+                        trigger = "deadline"
             if not self._pending:
                 continue
+            if self._counters is not None:
+                self._counters.inc(f"batcher_cuts_{trigger}")
             window = self._cut()
             try:
                 await self._flush(window)

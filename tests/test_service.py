@@ -22,6 +22,7 @@ from matchmaking_tpu.service.batcher import Batcher
 from matchmaking_tpu.service.broker import Delivery, InProcBroker, Properties
 from matchmaking_tpu.service.client import MatchmakingClient
 from matchmaking_tpu.testing.drain import settle
+from matchmaking_tpu.utils.metrics import Counter
 from matchmaking_tpu.service.middleware import (
     AuthMiddleware,
     DecodeMiddleware,
@@ -152,6 +153,79 @@ async def test_batcher_serializes_windows():
     await asyncio.sleep(0.3)
     assert max(overlap) == 1  # windows never overlap (atomicity)
     await b.close()
+
+
+@pytest.mark.parametrize("observed", [True, False],
+                         ids=["observe_window", "observe_none"])
+async def test_batcher_cuts_overdue_request_when_slow_flush_returns(observed):
+    # A request submitted during a 60 ms flush is 20 ms overdue when that
+    # flush returns, and is cut then — not after a fresh max_wait_ms.
+    cuts, returned = [], []
+
+    async def flush(w):
+        cuts.append(time.monotonic())
+        if len(cuts) == 1:
+            b.submit("late")
+            await asyncio.sleep(0.06)
+        returned.append(time.monotonic())
+
+    b = Batcher(BatcherConfig(max_batch=1000, max_wait_ms=20.0), flush,
+                observe_window=(lambda n, age: None) if observed else None)
+    b.submit("first")
+    t0 = time.monotonic()
+    while len(cuts) < 2:
+        assert time.monotonic() - t0 < 1.0
+        await asyncio.sleep(0.005)
+    assert cuts[1] - returned[0] < 0.010
+    assert cuts[1] - cuts[0] < 0.060 + 0.020
+    await b.close()
+
+
+async def test_batcher_counts_cuts_by_trigger():
+    counters = Counter()
+    windows = []
+
+    async def flush(w):
+        windows.append(list(w))
+        if len(windows) == 1:
+            b.submit("late")            # overdue when this flush returns
+            await asyncio.sleep(0.06)
+
+    b = Batcher(BatcherConfig(max_batch=4, max_wait_ms=20.0), flush,
+                counters=counters)
+    assert {k: counters.get(f"batcher_cuts_{k}")
+            for k in ("size", "deadline", "overdue")} == {
+                "size": 0, "deadline": 0, "overdue": 0}
+    for i in range(4):                  # full at once: the size trigger
+        b.submit(i)
+    t0 = time.monotonic()
+    while len(windows) < 2:
+        assert time.monotonic() - t0 < 1.0
+        await asyncio.sleep(0.005)
+    b.submit("alone")                   # idle queue: waits out its deadline
+    while len(windows) < 3:
+        assert time.monotonic() - t0 < 1.0
+        await asyncio.sleep(0.005)
+    await b.close()
+    assert windows == [[0, 1, 2, 3], ["late"], ["alone"]]
+    assert (counters.get("batcher_cuts_size"),
+            counters.get("batcher_cuts_deadline"),
+            counters.get("batcher_cuts_overdue")) == (1, 1, 1)
+
+
+async def test_app_exports_batcher_cut_counters():
+    app = MatchmakingApp(tiny_cfg())
+    await app.start()
+    client = MatchmakingClient(app.broker, "matchmaking.search")
+    a, b = (client.submit({"id": "alice", "rating": 1500}),
+            client.submit({"id": "bob", "rating": 1540}))
+    await client.next_response(a, timeout=15.0)
+    await client.next_response(b, timeout=15.0)
+    counters = app.metrics.report()["counters"]
+    cuts = [counters[f"batcher_cuts_{k}"]
+            for k in ("size", "deadline", "overdue")]
+    assert sum(cuts) >= 1
+    await app.stop()
 
 
 # ---- end-to-end -----------------------------------------------------------
